@@ -647,6 +647,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     if args.check_regression:
         raise SystemExit(check_regression(*args.check_regression,
                                           tol=args.tol))
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = list(BENCHES) if not args.only else [
         n.strip() for n in args.only.split(",") if n.strip()]
     unknown = [n for n in names if n not in BENCHES]

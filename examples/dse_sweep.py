@@ -38,6 +38,7 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MapperOptions, Toolchain
 from repro.dse import (SEARCH_ALGOS, SPACE_NAMES, SearchConfig, frontier,
                        frontier_table, get_space, run_search, run_sweep,
@@ -45,6 +46,7 @@ from repro.dse import (SEARCH_ALGOS, SPACE_NAMES, SearchConfig, frontier,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="CGRA architecture design-space explorer")
     ap.add_argument("--space", default="small", metavar="NAME",

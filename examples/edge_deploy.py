@@ -30,6 +30,7 @@ import time
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import CGRAArch, MapperOptions, Toolchain
 from repro.core.mapper import MapError
@@ -99,6 +100,7 @@ def emit_streams(arch_id: str, tokens: int, out_dir: str,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_IDS)
     ap.add_argument("--tokens", type=int, default=64)

@@ -27,6 +27,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CompiledKernel, KernelSpec, MapperOptions, Toolchain,
                         assign_layout, build_gemm, cluster_4x4)
 from repro.core.layout import ArrayDecl
@@ -35,6 +36,7 @@ from repro.frontend import KernelContext
 
 
 def main():
+    enable_compile_cache()
     # 1. architecture (ADL): 4x4 PEs, two 8 kB banks, 16-bit datapath
     arch = cluster_4x4()
     print(f"target: {arch.name}, {arch.rows}x{arch.cols} PEs, "

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, serve_smoke_config
 from repro.core import CGRAArch, MapperOptions, Toolchain
 from repro.models.zoo import build_model
@@ -71,6 +72,7 @@ def plain_demo(eng: Engine, vocab: int, seed: int) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_IDS)
     ap.add_argument("--cgra", action="store_true",

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config
 from repro.data.pipeline import DataConfig, Prefetcher, TokenSource
 from repro.models.zoo import build_model
@@ -36,6 +37,7 @@ def small_100m(tiny: bool = False):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -25,9 +25,6 @@ from __future__ import annotations
 
 from typing import Dict, List, Sequence, Tuple
 
-# module-level on purpose: importing this module asserts JAX availability,
-# so callers holding a numpy fallback (verify.reference_banks_batch) can
-# catch ImportError at import time rather than deep inside a call
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -140,6 +140,13 @@ def stats() -> Dict[str, int]:
                 "misses": _misses}
 
 
+def signatures() -> list:
+    """The signatures with a live executable, in build order (how a caller
+    tells a stacked ``multi=True`` launch from a group-of-one fallback)."""
+    with _lock:
+        return list(_entries)
+
+
 def clear() -> None:
     """Drop every cached executable (tests / memory pressure)."""
     global _misses

@@ -115,15 +115,8 @@ def reference_banks_batch(dfg, init_banks, invocations, mapped_iters: int,
     invocation), so the oracle does not become the bottleneck of batched
     verification.  The heavy lifting runs on the JAX-lowered DFG executor
     (``repro.core.refexec``); ``DFG.reference_execute_batch`` is its
-    bit-identical numpy reference (pinned by tests) and the fallback
-    wherever JAX is unavailable."""
-    try:
-        from .refexec import reference_execute_jax
-    except ImportError:
-        return dfg.reference_execute_batch(
-            mapped_iters, {k: np.asarray(v, dtype=np.int64)
-                           for k, v in init_banks.items()},
-            invocations, bits=bits)
+    bit-identical numpy reference (pinned by tests)."""
+    from .refexec import reference_execute_jax
     return reference_execute_jax(dfg, mapped_iters, init_banks,
                                  invocations, bits=bits)
 

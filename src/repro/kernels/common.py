@@ -6,33 +6,12 @@ against their pure-jnp oracles in ref.py.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces / compiler params (name moved across jax versions)
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-    CompilerParams = getattr(pltpu, "CompilerParams",
-                             getattr(pltpu, "TPUCompilerParams", None))
-except Exception:  # pragma: no cover - pallas tpu backend unavailable
-    pltpu = None
-    VMEM = None
-    CompilerParams = None
 
 # TPU v5e hardware alignment
 MXU = 128        # systolic array dim; matmul tiles should be multiples
 SUBLANE = 8      # fp32 sublane packing
 LANE = 128
-
-
-def compiler_params(dimension_semantics):
-    if CompilerParams is None:
-        return None
-    try:
-        return CompilerParams(dimension_semantics=dimension_semantics)
-    except TypeError:  # pragma: no cover
-        return None
 
 
 def cdiv(a: int, b: int) -> int:

@@ -17,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..common import VMEM, compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, KH, KW, OH, OW):
@@ -41,8 +40,7 @@ def conv2d_os_pallas(x: jnp.ndarray, w: jnp.ndarray, *, bco: int = 128,
     assert Cin == Cin2 and Cout % bco == 0
     OH, OW = H - KH + 1, W - KW + 1
     out_dtype = out_dtype or x.dtype
-    scratch = [VMEM((OH * OW, bco), jnp.float32)] if VMEM is not None else [
-        jax.ShapeDtypeStruct((OH * OW, bco), jnp.float32)]
+    scratch = [pltpu.VMEM((OH * OW, bco), jnp.float32)]
 
     return pl.pallas_call(
         functools.partial(_conv_kernel, KH=KH, KW=KW, OH=OH, OW=OW),
@@ -54,6 +52,7 @@ def conv2d_os_pallas(x: jnp.ndarray, w: jnp.ndarray, *, bco: int = 128,
         out_specs=pl.BlockSpec((1, OH, OW, bco), lambda n, c: (n, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((N, OH, OW, Cout), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -15,8 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common import VMEM, compiler_params
 
 NEG_INF = -1e30
 
@@ -65,8 +65,6 @@ def decode_attn_pallas(q, k, v, lengths, *, bs: int = 512, scale=None,
     assert S % bs == 0
     s_steps = S // bs
     scale = float(scale if scale is not None else 1.0 / (D ** 0.5))
-    mk = VMEM if VMEM is not None else (
-        lambda shp, dt: jax.ShapeDtypeStruct(shp, dt))
     return pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs, s_steps=s_steps,
                           scale=scale),
@@ -79,10 +77,10 @@ def decode_attn_pallas(q, k, v, lengths, *, bs: int = 512, scale=None,
         ],
         out_specs=pl.BlockSpec((1, G, D), lambda b, h, s: (b * Hkv + h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
-        scratch_shapes=[mk((G, 1), jnp.float32),
-                        mk((G, 1), jnp.float32),
-                        mk((G, D), jnp.float32)],
-        compiler_params=compiler_params(
-            ("arbitrary", "arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(lengths, q.reshape(B * Hkv, G, D), k, v).reshape(B, Hkv, G, D)

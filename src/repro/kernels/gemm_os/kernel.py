@@ -19,8 +19,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..common import VMEM, cdiv, compiler_params
+from ..common import cdiv
 
 
 def _apply_act(acc, activation):
@@ -84,8 +85,7 @@ def gemm_os_pallas(a: jnp.ndarray, b: jnp.ndarray,
     gm, gn, gk = M // bm, N // bn, K // bk
     out_dtype = out_dtype or a.dtype
     out_shape = jax.ShapeDtypeStruct((M, N), out_dtype)
-    scratch = [VMEM((bm, bn), jnp.float32)] if VMEM is not None else [
-        jax.ShapeDtypeStruct((bm, bn), jnp.float32)]
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
 
     if coalesce_grid:
         # Listing-4 analogue: one flat loop over output tiles; K innermost.
@@ -142,6 +142,6 @@ def gemm_os_pallas(a: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), o_idx),
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=compiler_params(semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(*args)

@@ -14,8 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..common import VMEM, compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _qgemm_kernel(a_ref, b_ref, sa_ref, sb_ref, o_ref, acc_ref, *, k_steps):
@@ -44,8 +43,7 @@ def qgemm_int8_pallas(a, b, a_scale, b_scale, *, bm: int = 128,
     _, N = b.shape
     assert M % bm == 0 and N % bn == 0 and K % bk == 0
     gm, gn, gk = M // bm, N // bn, K // bk
-    scratch = [VMEM((bm, bn), jnp.int32)] if VMEM is not None else [
-        jax.ShapeDtypeStruct((bm, bn), jnp.int32)]
+    scratch = [pltpu.VMEM((bm, bn), jnp.int32)]
     return pl.pallas_call(
         functools.partial(_qgemm_kernel, k_steps=gk),
         grid=(gm, gn, gk),
@@ -58,7 +56,7 @@ def qgemm_int8_pallas(a, b, a_scale, b_scale, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=compiler_params(
-            ("parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a, b, a_scale.reshape(M, 1), b_scale.reshape(1, N))

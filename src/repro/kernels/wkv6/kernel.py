@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..common import VMEM, compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _make_kernel(ct: int, s_steps: int):
@@ -32,15 +31,15 @@ def _make_kernel(ct: int, s_steps: int):
         u = u_ref[0].astype(jnp.float32)        # (D,)
 
         def body(i, S):
-            idx = (0, pl.dslice(i, 1), slice(None))
-            rt = pl.load(r_ref, idx)[0].astype(jnp.float32)
-            kt = pl.load(k_ref, idx)[0].astype(jnp.float32)
-            vt = pl.load(v_ref, idx)[0].astype(jnp.float32)
-            wt = pl.load(w_ref, idx)[0].astype(jnp.float32)
+            t = pl.ds(i, 1)
+            rt = r_ref[0, t, :][0].astype(jnp.float32)
+            kt = k_ref[0, t, :][0].astype(jnp.float32)
+            vt = v_ref[0, t, :][0].astype(jnp.float32)
+            wt = w_ref[0, t, :][0].astype(jnp.float32)
             kv = kt[:, None] * vt[None, :]
             out = jnp.dot(rt[None, :], S + u[:, None] * kv,
                           preferred_element_type=jnp.float32)
-            pl.store(o_ref, idx, out[None].astype(o_ref.dtype)[0])
+            o_ref[0, t, :] = out.astype(o_ref.dtype)
             return wt[:, None] * S + kv
 
         S = jax.lax.fori_loop(0, ct, body, state[...])
@@ -58,8 +57,6 @@ def wkv6_pallas(r, k, v, w, u, state0, *, ct: int = 64,
     H = u.shape[0]
     assert T % ct == 0
     s_steps = T // ct
-    mk = VMEM if VMEM is not None else (
-        lambda shp, dt: jax.ShapeDtypeStruct(shp, dt))
     kern = _make_kernel(ct, s_steps)
     out, sout = pl.pallas_call(
         kern,
@@ -76,8 +73,9 @@ def wkv6_pallas(r, k, v, w, u, state0, *, ct: int = 64,
                    pl.BlockSpec((1, D, D), lambda bh, s: (bh, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct((BH, T, D), r.dtype),
                    jax.ShapeDtypeStruct((BH, D, D), jnp.float32)),
-        scratch_shapes=[mk((D, D), jnp.float32)],
-        compiler_params=compiler_params(("arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(u, state0, r, k, v, w)
     return out, sout

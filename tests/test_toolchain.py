@@ -42,6 +42,23 @@ def test_compile_many_matches_individual(tc):
         ck.verify()     # process-pool results reassemble into working CKs
 
 
+def test_compile_worker_never_imports_jax():
+    # the parent may hold the chip; a pool worker that imported JAX could
+    # initialise a backend and contend for it
+    from pool_probe import compile_in_worker
+    from repro.core import pool
+    spec = small_gemm()
+    payload = json.dumps({"dfg": spec.dfg.to_json_dict(),
+                          "arch": json.loads(spec.arch.to_json()),
+                          "layout": spec.layout.to_json_dict(),
+                          "options": MapperOptions().to_json_dict()})
+    outs = pool.process_map(compile_in_worker, [payload, payload])
+    assert outs is not None, "no process pool: the fan-out went sequential"
+    for out, jax_modules in outs:
+        assert "mapping" in json.loads(out)
+        assert jax_modules == []
+
+
 def test_compile_many_dedups_identical_specs(tc):
     cks = tc.compile_many([small_gemm(), small_gemm()], jobs=2)
     assert cks[0] is cks[1]     # one compile served both indices
