@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import simcache
+from . import obs, simcache
 from .config_gen import (KIND_FUOUT, KIND_IMM, KIND_IN_E, KIND_IN_N,
                          KIND_IN_S, KIND_IN_W, KIND_LIREG, KIND_NONE,
                          KIND_REG, OPC, OPC_LOAD, OPC_NONE, OPC_PASS,
@@ -141,6 +141,13 @@ def _tile_bytes_per_cycle(c: Dict[str, jnp.ndarray], II: int) -> int:
     row's slot is streamed, so the per-cycle cost scales with B)."""
     return sum(int(np.prod(c[k].shape)) // II * c[k].dtype.itemsize
                for k in _SLOT_PLANES)
+
+
+def _pretiled(c: Dict[str, jnp.ndarray], II: int, n_cycles: int) -> bool:
+    """Whether the body pre-tiles the slot planes ``c`` into per-cycle
+    streams for an ``n_cycles`` scan, or gathers the slot every cycle.
+    Shapes and dtypes only, so host, device and traced planes agree."""
+    return n_cycles * _tile_bytes_per_cycle(c, II) <= _TILE_BYTES_LIMIT
 
 
 def _state_layout(P: int, RF: int, LI: int):
@@ -310,7 +317,7 @@ def _sim_body(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
     # Tiling is O(n_cycles) memory, so very long simulations (bounded by
     # _TILE_BYTES_LIMIT total tiled-stream bytes) keep the II-sized
     # planes and gather per cycle instead.
-    pretile = n_cycles * _tile_bytes_per_cycle(c, II) <= _TILE_BYTES_LIMIT
+    pretile = _pretiled(c, II, n_cycles)
     t_arr = jnp.arange(n_cycles)
     if pretile:
         slots = jnp.arange(n_cycles) % II
@@ -415,22 +422,62 @@ def _sim_body(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
     return mem.reshape(B, W)
 
 
-_run_invocations = functools.partial(
-    jax.jit, static_argnames=("II", "P", "RF", "bits", "n_iters",
-                              "n_cycles", "cfg_batched"))(_sim_body)
-
-
 def _build_batched(sig: simcache.SimSignature):
     """Compile-on-demand builder for one batched-simulator signature,
     jitted with the batched image buffer donated so per-seed images are
     updated in place.  Buffer donation is a device-memory optimization XLA
     only implements off-CPU, so it is skipped on the CPU backend (where it
-    would just warn)."""
-    body = functools.partial(_sim_body, II=sig.II, P=sig.P, RF=sig.RF,
-                             bits=sig.bits, n_iters=sig.n_iters,
-                             n_cycles=sig.n_cycles, cfg_batched=sig.multi)
+    would just warn).
+
+    The jitted functions are named, so their XLA modules are
+    ``jit_morpher_sim`` (one configuration's planes) and
+    ``jit_morpher_sim_multi`` (planes with a leading row axis) whatever
+    the signature; a jitted ``functools.partial`` would be ``jit__unknown``.
+    """
+    static = dict(II=sig.II, P=sig.P, RF=sig.RF, bits=sig.bits,
+                  n_iters=sig.n_iters, n_cycles=sig.n_cycles)
+
+    def morpher_sim(c, mem0, li_stack):
+        return _sim_body(c, mem0, li_stack, **static)
+
+    def morpher_sim_multi(c, mem0, li_stack):
+        return _sim_body(c, mem0, li_stack, cfg_batched=True, **static)
+
     donate = (1,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(body, donate_argnums=donate)
+    return jax.jit(morpher_sim_multi if sig.multi else morpher_sim,
+                   donate_argnums=donate)
+
+
+# executables of the per-seed ``simulate`` path, by exact signature (batch
+# 1, cycles unbucketed); kept apart from ``simcache``, which counts the
+# batched launches
+_build_single = functools.lru_cache(maxsize=None)(_build_batched)
+
+
+def _launch(sig: simcache.SimSignature, planes: Dict, mem: np.ndarray,
+            li_stack: np.ndarray, real_rows: int,
+            real_row_steps: int) -> np.ndarray:
+    """One batched executable call through its host result, under the
+    ``morpher.sim.launch`` span with the launch's counters as attrs:
+    scan steps launched (bucketed cycles x invocations), rows (bucketed
+    batch) and row-steps, the real rows and row-steps among them, whether
+    the body took the pre-tiled streams, and whether this launch built
+    the executable."""
+    n_inv = int(li_stack.shape[0])
+    steps = sig.n_cycles * n_inv
+    with obs.span("morpher.sim.launch", multi=sig.multi, invocations=n_inv,
+                  steps=steps, rows=sig.batch, real_rows=real_rows,
+                  row_steps=steps * sig.batch,
+                  real_row_steps=real_row_steps,
+                  pretiled=_pretiled(planes, sig.II, sig.n_cycles),
+                  built=False) as attrs:
+        def build():
+            attrs["built"] = True
+            return _build_batched(sig)
+
+        fn = simcache.get(sig, build)
+        return np.asarray(fn(planes, jnp.asarray(mem),
+                             jnp.asarray(li_stack)))
 
 
 def _banks_to_mem(cfg: SimConfig, banks: Dict[str, np.ndarray]) -> np.ndarray:
@@ -462,10 +509,10 @@ def simulate(cfg: SimConfig, banks: Dict[str, np.ndarray],
         return _mem_to_banks(cfg, mem, banks)
 
     li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
-    out = _run_invocations(
-        _as_jnp(cfg), jnp.asarray(mem[None, :]), jnp.asarray(li_stack),
-        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits,
-        n_iters=n_iters, n_cycles=cfg.n_cycles(n_iters))
+    fn = _build_single(simcache.SimSignature(
+        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
+        n_cycles=cfg.n_cycles(n_iters), batch=1))
+    out = fn(_as_jnp(cfg), jnp.asarray(mem[None, :]), jnp.asarray(li_stack))
     return _mem_to_banks(cfg, np.asarray(out)[0], banks)
 
 
@@ -486,21 +533,23 @@ def simulate_batch(cfg: SimConfig, banks_batch: List[Dict[str, np.ndarray]],
     B = len(banks_batch)
     if B == 0:
         return []
-    mem = np.stack([_banks_to_mem(cfg, banks) for banks in banks_batch])
-    if not len(invocations):
-        return [_mem_to_banks(cfg, mem[i], banks_batch[i]) for i in range(B)]
-
-    li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
-    sig = simcache.SimSignature(
-        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
-        n_cycles=simcache.bucket_cycles(cfg.n_cycles(n_iters)),
-        batch=simcache.bucket_batch(B))
-    if sig.batch > B:  # pad to the bucket; padded rows are masked out below
-        mem = np.concatenate(
-            [mem, np.repeat(mem[-1:], sig.batch - B, axis=0)])
-    fn = simcache.get(sig, lambda: _build_batched(sig))
-    out = np.asarray(fn(_as_jnp(cfg), jnp.asarray(mem),
-                        jnp.asarray(li_stack)))
+    with obs.span("morpher.sim.planes"):
+        mem = np.stack([_banks_to_mem(cfg, banks) for banks in banks_batch])
+        if not len(invocations):
+            return [_mem_to_banks(cfg, mem[i], banks_batch[i])
+                    for i in range(B)]
+        li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
+        real_cycles = cfg.n_cycles(n_iters)
+        sig = simcache.SimSignature(
+            II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
+            n_cycles=simcache.bucket_cycles(real_cycles),
+            batch=simcache.bucket_batch(B))
+        if sig.batch > B:  # pad to the bucket; padded rows masked out below
+            mem = np.concatenate(
+                [mem, np.repeat(mem[-1:], sig.batch - B, axis=0)])
+        planes = _as_jnp(cfg)
+    out = _launch(sig, planes, mem, li_stack, real_rows=B,
+                  real_row_steps=real_cycles * len(invocations) * B)
     return [_mem_to_banks(cfg, out[i], banks_batch[i]) for i in range(B)]
 
 
@@ -631,33 +680,37 @@ def simulate_multi(items: Sequence[Tuple[SimConfig,
         out[i] = simulate_batch(cfg, bb, inv, n_iters)
         return out
 
-    reps = [len(items[i][1]) for i in live]
-    B = sum(reps)
-    W = max(items[i][0].total_words for i in live)
-    mem = np.zeros((B, W), dtype=np.int16 if bits == 16 else np.int32)
-    row = 0
-    for i in live:
-        cfg, bb, _ = items[i]
-        for b in bb:
-            mem[row, :cfg.total_words] = _banks_to_mem(cfg, b)
-            row += 1
-    li = np.concatenate(
-        [np.repeat(np.stack([items[i][0].livein_array(inv)
-                             for inv in items[i][2]])[:, None],
-                   rep, axis=1)
-         for i, rep in zip(live, reps)], axis=1)       # [n_inv,B,P,LI]
-    sig = simcache.SimSignature(
-        II=II, P=P, RF=RF, bits=bits, n_iters=n_iters, n_cycles=n_cycles,
-        batch=simcache.bucket_rows(B), LI=LI, multi=True)
-    pad = sig.batch - B
-    if pad:  # pad to the bucket by repeating the last row everywhere
-        mem = np.concatenate([mem, np.repeat(mem[-1:], pad, axis=0)])
-        li = np.concatenate([li, np.repeat(li[:, -1:], pad, axis=1)],
-                            axis=1)
-    planes = _stacked_jnp_planes(tuple(items[i][0] for i in live),
-                                 tuple(reps), pad, RF)
-    fn = simcache.get(sig, lambda: _build_batched(sig))
-    res = np.asarray(fn(planes, jnp.asarray(mem), jnp.asarray(li)))
+    with obs.span("morpher.sim.planes"):
+        reps = [len(items[i][1]) for i in live]
+        B = sum(reps)
+        W = max(items[i][0].total_words for i in live)
+        mem = np.zeros((B, W), dtype=np.int16 if bits == 16 else np.int32)
+        row = 0
+        for i in live:
+            cfg, bb, _ = items[i]
+            for b in bb:
+                mem[row, :cfg.total_words] = _banks_to_mem(cfg, b)
+                row += 1
+        li = np.concatenate(
+            [np.repeat(np.stack([items[i][0].livein_array(inv)
+                                 for inv in items[i][2]])[:, None],
+                       rep, axis=1)
+             for i, rep in zip(live, reps)], axis=1)   # [n_inv,B,P,LI]
+        sig = simcache.SimSignature(
+            II=II, P=P, RF=RF, bits=bits, n_iters=n_iters,
+            n_cycles=n_cycles, batch=simcache.bucket_rows(B), LI=LI,
+            multi=True)
+        pad = sig.batch - B
+        if pad:  # pad to the bucket by repeating the last row everywhere
+            mem = np.concatenate([mem, np.repeat(mem[-1:], pad, axis=0)])
+            li = np.concatenate([li, np.repeat(li[:, -1:], pad, axis=1)],
+                                axis=1)
+        planes = _stacked_jnp_planes(tuple(items[i][0] for i in live),
+                                     tuple(reps), pad, RF)
+    real_row_steps = sum(items[i][0].n_cycles(n_iters) * n_inv * rep
+                         for i, rep in zip(live, reps))
+    res = _launch(sig, planes, mem, li, real_rows=B,
+                  real_row_steps=real_row_steps)
     row = 0
     for i in live:
         cfg, bb, _ = items[i]

@@ -43,6 +43,7 @@ from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from . import obs
 from .adl import CGRAArch
 from .config_gen import ConfigConflict, SimConfig, generate_config
 from .dfg import DFG
@@ -114,6 +115,15 @@ def _compile_worker(payload: str) -> str:
         return json.dumps({"map_error": _compile_error_str(e)})
     return json.dumps({"mapping": mapping.to_json_dict(),
                        "cfg": json.loads(cfg.to_json())})
+
+
+def _map_in_process(spec: KernelSpec, opt: MapperOptions):
+    """Map one kernel and generate its configuration in this process."""
+    with obs.span("morpher.map", kernel=spec.name, pool=False):
+        mapping = map_kernel_opts(spec.dfg, spec.arch, spec.layout, opt)
+    with obs.span("morpher.config_gen", kernel=spec.name):
+        cfg = generate_config(mapping, spec.layout)
+    return mapping, cfg
 
 
 def _compile_error_str(e: Exception) -> str:
@@ -210,12 +220,7 @@ class CompiledKernel:
         reference execution on deterministic random bank images — the same
         bit-exact contract, self-contained in the artifact.
         """
-        from .verify import check_enabled
-        if check_enabled():
-            # opt-in static gate (MORPHER_CHECK=1): a clean artifact must
-            # be diagnostic-free before any simulation runs
-            from ..check import assert_clean
-            assert_clean(self)
+        _check_gate([self])
         if self.spec is not None:
             from .verify import check_dfg_semantics, generate_test_data
             data = generate_test_data(self.spec, seed)
@@ -239,13 +244,7 @@ class CompiledKernel:
                     f"{self.name} (II={self.II}): simulation mismatch in "
                     f"{bank} at words {bad.tolist()}: got {got[bad]}, "
                     f"want {exp[bad]}")
-        from .verify import xval_enabled
-        if xval_enabled():
-            # opt-in second oracle (MORPHER_XVAL=1): the exported
-            # instruction stream through the standalone interpreter must
-            # also match the simulator bit-for-bit
-            from ..isa.xval import cross_validate
-            cross_validate(self, seeds=(seed,))
+        _xval_gate([self], (seed,))
         return self
 
     def verify_batch(self, seeds: Sequence[int] = (0,),
@@ -266,17 +265,13 @@ class CompiledKernel:
         seeds = list(seeds)
         if not seeds:
             return self
-        from .verify import check_enabled
-        if check_enabled():
-            from ..check import assert_clean
-            assert_clean(self)
-        init_batch, expected = _batch_oracle(self, seeds, check_dfg)
-        finals = self.run_batch(init_batch)
-        _check_batch(self, seeds, init_batch, expected, finals)
-        from .verify import xval_enabled
-        if xval_enabled():
-            from ..isa.xval import cross_validate
-            cross_validate(self, seeds=seeds)
+        with obs.span("morpher.verify_batch", kernel=self.name,
+                      seeds=len(seeds)):
+            _check_gate([self])
+            init_batch, expected = _batch_oracle(self, seeds, check_dfg)
+            finals = self.run_batch(init_batch)
+            _check_batch(self, seeds, init_batch, expected, finals)
+            _xval_gate([self], seeds)
         return self
 
     # --------------------------------------------------------- serialization
@@ -316,6 +311,30 @@ class CompiledKernel:
 
 
 # --------------------------------------------------------------------------
+def _check_gate(kernels: Sequence[CompiledKernel]) -> None:
+    """Opt-in static gate (``MORPHER_CHECK=1``): every artifact must be
+    diagnostic-free before any simulation runs."""
+    from .verify import check_enabled
+    if check_enabled():
+        from ..check import assert_clean
+        with obs.span("morpher.check", kernels=len(kernels)):
+            for ck in kernels:
+                assert_clean(ck)
+
+
+def _xval_gate(kernels: Sequence[CompiledKernel],
+               seeds: Sequence[int]) -> None:
+    """Opt-in second oracle (``MORPHER_XVAL=1``): the exported instruction
+    stream through the standalone interpreter must also match the
+    simulator bit-for-bit."""
+    from .verify import xval_enabled
+    if xval_enabled():
+        from ..isa.xval import cross_validate
+        with obs.span("morpher.xval", kernels=len(kernels)):
+            for ck in kernels:
+                cross_validate(ck, seeds=seeds)
+
+
 def _batch_oracle(ck: CompiledKernel, seeds: Sequence[int],
                   check_dfg: bool):
     """Test vectors + expected final banks for one kernel over a seed
@@ -327,14 +346,16 @@ def _batch_oracle(ck: CompiledKernel, seeds: Sequence[int],
     if ck.spec is not None:
         from .verify import (check_dfg_semantics_batch,
                              generate_test_data_batch)
-        data = generate_test_data_batch(ck.spec, seeds)
+        with obs.span("morpher.testdata"):
+            data = generate_test_data_batch(ck.spec, seeds)
+            init_batch = [data.init_row(i) for i in range(len(seeds))]
         if check_dfg:
             check_dfg_semantics_batch(ck.spec, data)
-        init_batch = [data.init_row(i) for i in range(len(seeds))]
         expected = data.expected_banks
     else:
         from .verify import reference_banks_batch
-        init_batch = [ck.random_banks(s) for s in seeds]
+        with obs.span("morpher.testdata"):
+            init_batch = [ck.random_banks(s) for s in seeds]
         expected = reference_banks_batch(
             ck.dfg,
             {k: np.stack([ib[k] for ib in init_batch])
@@ -351,19 +372,20 @@ def _check_batch(ck: CompiledKernel, seeds: Sequence[int],
     untouched.  Raises AssertionError naming the first offending
     (seed, bank, words)."""
     live = set(ck.liveout_banks())
-    for i, (seed, final) in enumerate(zip(seeds, finals)):
-        for bank in sorted(final):
-            got = np.asarray(final[bank])
-            # non-liveout banks have no oracle data to compare; they
-            # must simply come back untouched
-            exp = np.asarray(expected[bank][i] if bank in live
-                             else init_batch[i][bank])
-            if not np.array_equal(got, exp):
-                bad = np.nonzero(got != exp)[0][:8]
-                raise AssertionError(
-                    f"{ck.name} (II={ck.II}, seed={seed}): batched "
-                    f"simulation mismatch in {bank} at words "
-                    f"{bad.tolist()}: got {got[bad]}, want {exp[bad]}")
+    with obs.span("morpher.compare"):
+        for i, (seed, final) in enumerate(zip(seeds, finals)):
+            for bank in sorted(final):
+                got = np.asarray(final[bank])
+                # non-liveout banks have no oracle data to compare; they
+                # must simply come back untouched
+                exp = np.asarray(expected[bank][i] if bank in live
+                                 else init_batch[i][bank])
+                if not np.array_equal(got, exp):
+                    bad = np.nonzero(got != exp)[0][:8]
+                    raise AssertionError(
+                        f"{ck.name} (II={ck.II}, seed={seed}): batched "
+                        f"simulation mismatch in {bank} at words "
+                        f"{bad.tolist()}: got {got[bad]}, want {exp[bad]}")
 
 
 def verify_stacked(kernels: Sequence[CompiledKernel],
@@ -386,31 +408,26 @@ def verify_stacked(kernels: Sequence[CompiledKernel],
     seeds = list(seeds)
     if not seeds or not kernels:
         return kernels
-    from .verify import check_enabled
-    if check_enabled():
-        from ..check import assert_clean
-        for ck in kernels:
-            assert_clean(ck)
-    groups: Dict[tuple, List[int]] = {}
-    for idx, ck in enumerate(kernels):
-        sig = stack_signature(ck.cfg, ck.mapped_iters,
-                              len(ck.invocations))
-        groups.setdefault(sig, []).append(idx)
-    for sig in sorted(groups):
-        idxs = groups[sig]
-        prep = [(kernels[i],) + _batch_oracle(kernels[i], seeds, check_dfg)
-                for i in idxs]
-        finals = simulate_multi(
-            [(ck.cfg, init_batch, ck.invocations)
-             for ck, init_batch, _exp in prep],
-            n_iters=kernels[idxs[0]].mapped_iters)
-        for (ck, init_batch, expected), f in zip(prep, finals):
-            _check_batch(ck, seeds, init_batch, expected, f)
-    from .verify import xval_enabled
-    if xval_enabled():
-        from ..isa.xval import cross_validate
-        for ck in kernels:
-            cross_validate(ck, seeds=seeds)
+    with obs.span("morpher.verify_stacked", kernels=len(kernels),
+                  seeds=len(seeds)):
+        _check_gate(kernels)
+        groups: Dict[tuple, List[int]] = {}
+        for idx, ck in enumerate(kernels):
+            sig = stack_signature(ck.cfg, ck.mapped_iters,
+                                  len(ck.invocations))
+            groups.setdefault(sig, []).append(idx)
+        for sig in sorted(groups):
+            idxs = groups[sig]
+            prep = [(kernels[i],) + _batch_oracle(kernels[i], seeds,
+                                                  check_dfg)
+                    for i in idxs]
+            finals = simulate_multi(
+                [(ck.cfg, init_batch, ck.invocations)
+                 for ck, init_batch, _exp in prep],
+                n_iters=kernels[idxs[0]].mapped_iters)
+            for (ck, init_batch, expected), f in zip(prep, finals):
+                _check_batch(ck, seeds, init_batch, expected, f)
+        _xval_gate(kernels, seeds)
     return kernels
 
 
@@ -629,8 +646,7 @@ class Toolchain:
                 # err already carries the kernel name (mapper formatting)
                 raise MapError(f"{err} [cached result]")
         try:
-            mapping = map_kernel_opts(spec.dfg, spec.arch, spec.layout, opt)
-            cfg = generate_config(mapping, spec.layout)
+            mapping, cfg = _map_in_process(spec, opt)
         except (MapError, ConfigConflict) as e:
             if use_cache:
                 self._cache_store_error(key, _compile_error_str(e), opt)
@@ -676,97 +692,101 @@ class Toolchain:
         its infeasible points.
         """
         specs = [self._bind(s) for s in specs]
-        opt = options or self.options
-        self.last_fleet_report = None   # set again iff a fan-out runs
-        keys = [spec_cache_key(s, opt) for s in specs]
-        results: List[Optional[CompiledKernel]] = [None] * len(specs)
-        todo: Dict[str, List[int]] = {}      # cache_key -> spec indices
+        with obs.span("morpher.compile_many",
+                      specs=len(specs)) as attrs:
+            opt = options or self.options
+            self.last_fleet_report = None   # set again iff a fan-out runs
+            keys = [spec_cache_key(s, opt) for s in specs]
+            results: List[Optional[CompiledKernel]] = [None] * len(specs)
+            todo: Dict[str, List[int]] = {}      # cache_key -> spec indices
 
-        def unmapped(idxs: List[int], err: str) -> None:
-            if not allow_unmapped:
-                # err already carries the kernel name (mapper formatting)
-                raise MapError(err)
+            def unmapped(idxs: List[int], err: str) -> None:
+                if not allow_unmapped:
+                    # err already carries the kernel name (mapper formatting)
+                    raise MapError(err)
 
-        for i, (spec, key) in enumerate(zip(specs, keys)):
-            hit = self._lookup(key, spec) if use_cache else None
-            if hit is not None:
-                results[i] = hit
-                continue
-            err = self._cache_load_error(key) if use_cache else None
-            if err is not None:
-                unmapped([i], f"{err} [cached result]")
-                continue    # allow_unmapped: stays None
-            todo.setdefault(key, []).append(i)
+            for i, (spec, key) in enumerate(zip(specs, keys)):
+                hit = self._lookup(key, spec) if use_cache else None
+                if hit is not None:
+                    results[i] = hit
+                    continue
+                err = self._cache_load_error(key) if use_cache else None
+                if err is not None:
+                    unmapped([i], f"{err} [cached result]")
+                    continue    # allow_unmapped: stays None
+                todo.setdefault(key, []).append(i)
+            attrs["cache_hits"] = sum(r is not None for r in results)
 
-        def finish(key: str, idxs: List[int], mapping: Mapping,
-                   cfg: SimConfig) -> None:
-            ck = self._finish(specs[idxs[0]], opt, key, mapping, cfg,
-                              use_cache)
-            for i in idxs:
-                results[i] = ck
+            def finish(key: str, idxs: List[int], mapping: Mapping,
+                       cfg: SimConfig) -> None:
+                ck = self._finish(specs[idxs[0]], opt, key, mapping, cfg,
+                                  use_cache)
+                for i in idxs:
+                    results[i] = ck
 
-        if jobs is None:
-            jobs = min(len(todo), os.cpu_count() or 1) or 1
-        if fleet is not None:
-            # an explicit fleet config is a request to shard: even a
-            # 1-CPU host runs the supervised fan-out so fault injection
-            # and the recovery paths behave identically everywhere
-            jobs = max(jobs, fleet.groups)
-        order = list(todo.items())
-        if len(order) > 1 and jobs > 1:
-            payloads = [json.dumps({
-                "dfg": specs[idxs[0]].dfg.to_json_dict(),
-                "arch": json.loads(specs[idxs[0]].arch.to_json()),
-                "layout": specs[idxs[0]].layout.to_json_dict(),
-                "options": opt.to_json_dict(),
-            }) for _key, idxs in order]
-            # the supervised fleet runner sits on the shared pool (which
-            # handles start-method selection, REPL-driver detection and
-            # nested-worker suppression) and adds deadlines, retry and
-            # killed-worker recovery; results=None means no fan-out is
-            # available here — go sequential.  A unit failing past its
-            # retry budget (FleetError) degrades the same way: the
-            # sequential path is bit-identical by contract.
-            from ..dist.fleet import FleetConfig, FleetError, run_fleet
-            fcfg = fleet if fleet is not None else FleetConfig()
-            if fcfg.max_inflight is None:
-                import dataclasses
-                fcfg = dataclasses.replace(fcfg, max_inflight=jobs)
-            try:
-                report = run_fleet(_compile_worker, payloads, fcfg,
-                                   inline_fallback=False)
-                outs = report.results
-            except FleetError:
-                report, outs = None, None
-            self.last_fleet_report = report
-            if outs is not None:
-                for (key, idxs), out in zip(order, outs):
-                    d = json.loads(out)
-                    if "map_error" in d:
-                        if use_cache:
-                            self._cache_store_error(key, d["map_error"],
-                                                    opt)
-                        unmapped(idxs, d["map_error"])
-                        continue
-                    spec = specs[idxs[0]]
-                    finish(key, idxs,
-                           Mapping.from_json_dict(d["mapping"], spec.dfg,
-                                                  spec.arch),
-                           SimConfig.from_json(json.dumps(d["cfg"])))
-                order = []
-        for key, idxs in order:              # sequential path / fallback
-            spec = specs[idxs[0]]
-            try:
-                mapping = map_kernel_opts(spec.dfg, spec.arch, spec.layout,
-                                          opt)
-                cfg = generate_config(mapping, spec.layout)
-            except (MapError, ConfigConflict) as e:
-                if use_cache:
-                    self._cache_store_error(key, _compile_error_str(e), opt)
-                unmapped(idxs, _compile_error_str(e))
-                continue
-            finish(key, idxs, mapping, cfg)
-        return results
+            if jobs is None:
+                jobs = min(len(todo), os.cpu_count() or 1) or 1
+            if fleet is not None:
+                # an explicit fleet config is a request to shard: even a
+                # 1-CPU host runs the supervised fan-out so fault injection
+                # and the recovery paths behave identically everywhere
+                jobs = max(jobs, fleet.groups)
+            order = list(todo.items())
+            if len(order) > 1 and jobs > 1:
+                payloads = [json.dumps({
+                    "dfg": specs[idxs[0]].dfg.to_json_dict(),
+                    "arch": json.loads(specs[idxs[0]].arch.to_json()),
+                    "layout": specs[idxs[0]].layout.to_json_dict(),
+                    "options": opt.to_json_dict(),
+                }) for _key, idxs in order]
+                # the supervised fleet runner sits on the shared pool (which
+                # handles start-method selection, REPL-main detection and
+                # nested-worker suppression) and adds deadlines, retry and
+                # killed-worker recovery; results=None means no fan-out is
+                # available here — go sequential.  A unit failing past its
+                # retry budget (FleetError) degrades the same way: the
+                # sequential path is bit-identical by contract.
+                from ..dist.fleet import FleetConfig, FleetError, run_fleet
+                fcfg = fleet if fleet is not None else FleetConfig()
+                if fcfg.max_inflight is None:
+                    import dataclasses
+                    fcfg = dataclasses.replace(fcfg, max_inflight=jobs)
+                try:
+                    with obs.span("morpher.map", units=len(payloads),
+                                  pool=True):
+                        report = run_fleet(_compile_worker, payloads, fcfg,
+                                           inline_fallback=False)
+                    outs = report.results
+                except FleetError:
+                    report, outs = None, None
+                self.last_fleet_report = report
+                if outs is not None:
+                    for (key, idxs), out in zip(order, outs):
+                        d = json.loads(out)
+                        if "map_error" in d:
+                            if use_cache:
+                                self._cache_store_error(key, d["map_error"],
+                                                        opt)
+                            unmapped(idxs, d["map_error"])
+                            continue
+                        spec = specs[idxs[0]]
+                        finish(key, idxs,
+                               Mapping.from_json_dict(d["mapping"], spec.dfg,
+                                                      spec.arch),
+                               SimConfig.from_json(json.dumps(d["cfg"])))
+                    order = []
+            for key, idxs in order:              # sequential path / fallback
+                spec = specs[idxs[0]]
+                try:
+                    mapping, cfg = _map_in_process(spec, opt)
+                except (MapError, ConfigConflict) as e:
+                    if use_cache:
+                        self._cache_store_error(key, _compile_error_str(e),
+                                                opt)
+                    unmapped(idxs, _compile_error_str(e))
+                    continue
+                finish(key, idxs, mapping, cfg)
+            return results
 
     # --------------------------------------------- instruction-stream export
     def export_streams(self, kernel, out_dir: str,

@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from . import obs
 from .config_gen import SimConfig, generate_config
 from .kernels_lib import KernelSpec
 from .mapper import Mapping
@@ -117,8 +118,10 @@ def reference_banks_batch(dfg, init_banks, invocations, mapped_iters: int,
     (``repro.core.refexec``); ``DFG.reference_execute_batch`` is its
     bit-identical numpy reference (pinned by tests)."""
     from .refexec import reference_execute_jax
-    return reference_execute_jax(dfg, mapped_iters, init_banks,
-                                 invocations, bits=bits)
+    rows = len(next(iter(init_banks.values()))) if init_banks else 0
+    with obs.span("morpher.oracle", rows=rows):
+        return reference_execute_jax(dfg, mapped_iters, init_banks,
+                                     invocations, bits=bits)
 
 
 def check_dfg_semantics(spec: KernelSpec, data: TestData) -> None:
