@@ -1,7 +1,7 @@
 """Cycle-accurate functional CGRA simulator in JAX (paper Fig. 3 piece 8).
 
 Morpher simulates the generated Verilog with Verilator; here the same
-contract is met by a jit-compiled `lax.scan` over cycles that executes the
+contract is met by a jit-compiled loop over cycles that executes the
 configuration bitstreams exactly as the RTL control memories would:
 
   * every cycle, every PE reads its slot-(t mod II) configuration,
@@ -14,10 +14,22 @@ configuration bitstreams exactly as the RTL control memories would:
   * crossbar output registers and RF writes update from the same
     start-of-cycle snapshot (fully synchronous design).
 
-All PEs are vectorized; the cycle loop is a `lax.scan`; invocations (the
-host-driven outer loops) are a second `lax.scan` threading the memory
-image.  This is the component that makes verification fast enough to run
-in CI for every mapped kernel.
+All PEs are vectorized.  The cycle loop has two bodies, with the same
+cycle word for word:
+
+  * the scan (``_sim_body``): the cycle loop is a ``lax.scan`` and the
+    invocations (the host-driven outer loops) a second ``lax.scan``
+    threading the memory image.  It runs on every backend but a TPU, for
+    the multi-configuration planes of ``simulate_multi`` everywhere, and
+    where the VMEM body's footprint would pass its budget;
+  * the VMEM body (``_vmem_sim``, TPU only): one Pallas kernel per launch
+    runs every invocation and every cycle in in-kernel loops, with the
+    fabric state, the images, the slot planes and the live-ins resident in
+    VMEM.  ``_body`` decides from the backend and the shapes alone, for
+    the traced function and the launch counters alike.
+
+This is the component that makes verification fast enough to run in CI
+for every mapped kernel.
 
 Both entry points run one shared traced body with a leading batch axis of
 memory images (``simulate`` is the batch-of-one case):
@@ -35,8 +47,8 @@ memory images (``simulate`` is the batch-of-one case):
     search.  Per (config, image) row the computation is op-for-op the
     single-config body, so results stay bit-identical.
 
-The body is hand-batched rather than ``vmap``-ed, and shaped around what
-profiles as expensive on small CGRA configurations:
+The scan body is hand-batched rather than ``vmap``-ed, and shaped around
+what profiles as expensive on small CGRA configurations:
 
   * the batch axis rides the PE dimension of every dense op, where it
     amortizes per-op dispatch nearly for free;
@@ -60,6 +72,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import obs, simcache
 from .config_gen import (KIND_FUOUT, KIND_IMM, KIND_IN_E, KIND_IN_N,
@@ -198,6 +212,19 @@ def _port_gather_idx(kind: np.ndarray, idx: np.ndarray, cfg: SimConfig,
                       else np.int32)
 
 
+def _lane_table(op: np.ndarray, opcode: int) -> np.ndarray:
+    """Per slot, the PE indices whose opcode is ``opcode``, padded with -1
+    to the busiest slot's count (at least one column): ``[II, n]``."""
+    op = np.asarray(op)
+    lanes = [np.nonzero(row == opcode)[0] for row in op]
+    n = max(1, max(len(l) for l in lanes))
+    out = np.full((op.shape[0], n), -1,
+                  dtype=np.int8 if op.shape[1] <= 127 else np.int16)
+    for s, l in enumerate(lanes):
+        out[s, :len(l)] = l
+    return out
+
+
 def _host_planes(cfg: SimConfig,
                  rf_pad: int = 0) -> Dict[str, np.ndarray]:
     """Host-side compilation of a SimConfig into the simulator's slot
@@ -207,8 +234,9 @@ def _host_planes(cfg: SimConfig,
     Starting from the dtype-narrowed planes, the three mux banks are
     compiled into one ``port_idx`` gather plane over the flat state
     vector, write masks replace the RF/crossbar kind tests, and the
-    per-slot store-lane table is derived from the opcode plane (see
-    ``_SLOT_PLANES``).  With ``rf_pad > cfg.RF`` the RF write-port bank
+    per-slot store- and load-lane tables are derived from the opcode
+    plane (see ``_SLOT_PLANES``; only the VMEM body reads the load
+    lanes).  With ``rf_pad > cfg.RF`` the RF write-port bank
     pads to ``rf_pad`` ports with unconfigured (KIND_NONE, mask-off)
     lanes and the state layout stretches to match — the padded register
     rows are never written or read, which is what lets fabrics with
@@ -232,14 +260,7 @@ def _host_planes(cfg: SimConfig,
     cached = by_rf.get(R)
     if cached is None:
         p = narrowed_planes(cfg)
-        II, P, LI = cfg.II, cfg.P, max(1, cfg.LI)
-        lanes = [np.nonzero(np.asarray(cfg.op)[s] == OPC_STORE)[0]
-                 for s in range(II)]
-        S = max(1, max((len(l) for l in lanes), default=0))
-        store_lanes = np.full((II, S), -1, dtype=np.int8 if P <= 127
-                              else np.int16)
-        for s, l in enumerate(lanes):
-            store_lanes[s, :len(l)] = l
+        LI = max(1, cfg.LI)
         rf_kind = np.asarray(p["rf_kind"])
         rf_idx = np.asarray(p["rf_idx"])
         if R > cfg.RF:                   # pad write-port bank: dead lanes
@@ -260,7 +281,8 @@ def _host_planes(cfg: SimConfig,
             "mem_off": np.asarray(p["mem_off"]),
             "mem_words": np.asarray(p["mem_words"]),
             "valid_start": np.asarray(p["valid_start"]),
-            "store_lanes": store_lanes,
+            "store_lanes": _lane_table(cfg.op, OPC_STORE),
+            "load_lanes": _lane_table(cfg.op, OPC_LOAD),
         }
         for k in SimConfig._ARRAY_DTYPES:
             arr = getattr(cfg, k)
@@ -422,6 +444,276 @@ def _sim_body(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
     return mem.reshape(B, W)
 
 
+# ------------------------------------------------- VMEM-resident cycle body
+# The TPU body: one Pallas kernel per launch runs every invocation and
+# every cycle in in-kernel loops, with the fabric state, the memory images,
+# the II-sized slot planes and the live-ins resident in VMEM throughout.
+# Values ride int32 carriers wrapped to the datapath width (``_wrap``), and
+# every array is laid out in 128-lane blocks:
+#
+#   * the carried state is ``X`` = [ xo (P*4) | regs (P*RF) ] and ``F`` =
+#     the FU output registers, one lane per PE; the per-slot immediates and
+#     the invocation's live-ins form a constant block ``C`` = [ imm (P) |
+#     li (P*LI) ];
+#   * the mux fabric resolves as a one-hot matmul ``[X | F] @ oh[slot] +
+#     C @ ohc[slot]`` into [ write ports in ``X``'s layout | operands a, b,
+#     c at lanes k*P + pe ].  A column has at most one nonzero term and the
+#     values enter as 8-bit pieces that bf16 holds exactly, so the f32
+#     accumulation is exact; an unconfigured port has no term and reads 0;
+#   * LOADs and STOREs touch only the slot's load / store lanes (SMEM
+#     tables), by iota compares over the image row; a gated-off store
+#     writes nothing;
+#   * step t of the cycle loop runs cycle t's mux and ALU beside cycle
+#     t-1's memory work, which depends on nothing of cycle t: a LOAD's word
+#     reaches an FU register only at the end of the next cycle, so the
+#     image passes overlap the matmul instead of following it.
+_VMEM_BUDGET = 48 << 20
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def _vmem_layout(P: int, RF: int, LI: int) -> Tuple[int, int, int, int]:
+    """Lane widths of the VMEM body's blocks: ``X`` (crossbar outputs and
+    registers), per-PE vectors, the constant block ``C`` (immediates and
+    live-ins) and the packed operand block."""
+    return (_lanes(P * (4 + RF)), _lanes(P), _lanes(P * (1 + LI)),
+            _lanes(3 * P))
+
+
+def _vmem_bytes(B: int, W: int, P: int, RF: int, LI: int, II: int,
+                n_inv: int) -> int:
+    """VMEM the kernel body holds for these shapes: the image in and out
+    plus two image-sized temporaries, the one-hot mux planes (bf16), the
+    live-in rows, and the [II, 1, N] slot planes and scratch, whose single
+    row the (8, 128) tiling pads to 8."""
+    XW, PL, CW, OW = _vmem_layout(P, RF, LI)
+    NW = XW + OW
+    image = -(-B // 8) * 8 * _lanes(W) * 4
+    return (4 * image + II * (XW + PL + CW) * NW * 2
+            + -(-n_inv // 8) * 8 * CW * 4
+            + II * 8 * 4 * (XW + 2 * OW + PL + CW + NW))
+
+
+def _body(multi: bool, B: int, W: int, P: int, RF: int, LI: int, II: int,
+          n_inv: int) -> str:
+    """Which body a launch of these shapes runs: ``"vmem"`` (the Pallas
+    kernel) on a TPU backend for single-configuration planes whose
+    footprint fits ``_VMEM_BUDGET``, else ``"scan"``.  Shared by the traced
+    function and the launch counters, so the two cannot disagree."""
+    if (jax.default_backend() != "tpu" or multi
+            or _vmem_bytes(B, W, P, RF, LI, II, n_inv) > _VMEM_BUDGET):
+        return "scan"
+    return "vmem"
+
+
+def _mux_maps(P: int, RF: int, LI: int):
+    """Static index maps from the scan's flat state layout
+    (``_state_layout``) and port order to the VMEM body's blocks: the row
+    of ``[X | F]`` and the row of ``C`` each state cell feeds (-1 where
+    none; the scan's zero cell feeds neither), and the output column of
+    each [P, 3+RF+4] mux port."""
+    XW, PL, CW, OW = _vmem_layout(P, RF, LI)
+    xo_off, reg_off, fu_off, imm_off, li_off, zero_off = \
+        _state_layout(P, RF, LI)
+    row = np.full(zero_off + 1, -1, np.int32)
+    crow = np.full(zero_off + 1, -1, np.int32)
+    row[:fu_off] = np.arange(fu_off)                 # xo, then registers
+    row[fu_off:imm_off] = XW + np.arange(P)
+    crow[imm_off:li_off] = np.arange(P)
+    crow[li_off:zero_off] = P + np.arange(P * LI)
+    pe = np.arange(P)[:, None]
+    col = np.concatenate([
+        XW + np.arange(3)[None, :] * P + pe,         # operands
+        reg_off + pe * RF + np.arange(RF)[None, :],  # register writes
+        xo_off + pe * 4 + np.arange(4)[None, :],     # crossbar writes
+    ], axis=1)                                       # [P, 3+RF+4]
+    return row, crow, col
+
+
+def _one_hot(port_idx: jnp.ndarray, to_row: np.ndarray, col: np.ndarray,
+             rows: int, cols: int) -> jnp.ndarray:
+    """[II, rows, cols] bf16: 1 where output column ``col[pe, k]`` reads
+    block row ``to_row[port_idx[slot, pe, k]]``."""
+    II = port_idx.shape[0]
+    src = jnp.take(jnp.asarray(to_row), port_idx.astype(jnp.int32)
+                   .reshape(II, -1))
+    by_col = jnp.full((II, cols), -1, jnp.int32).at[:, col.reshape(-1)].set(
+        src)
+    return (by_col[:, None, :] == jnp.arange(rows)[None, :, None]).astype(
+        jnp.bfloat16)
+
+
+def _mux(x: jnp.ndarray, oh: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """``x @ oh`` exactly for a one-hot ``oh`` and ``bits``-wide signed
+    values: 8-bit pieces (the low ones unsigned), stacked along rows, each
+    exact in bf16, recombined in int32."""
+    n = -(-bits // 8)
+    R = x.shape[0]
+    pieces = [(x >> (8 * k)) & 255 for k in range(n - 1)] + \
+        [x >> (8 * (n - 1))]
+    xs = jnp.concatenate(pieces, axis=0).astype(jnp.float32).astype(
+        jnp.bfloat16)
+    y = jnp.dot(xs, oh, preferred_element_type=jnp.float32).astype(
+        jnp.int32)
+    out = y[(n - 1) * R:]
+    for k in range(n - 2, -1, -1):
+        out = out * 256 + y[k * R:(k + 1) * R]
+    return out
+
+
+def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
+              li_stack: jnp.ndarray, *, II: int, P: int, RF: int,
+              bits: int, n_iters: int, n_cycles: int,
+              interpret: bool = False) -> jnp.ndarray:
+    """``_sim_body`` of single-configuration planes as one Pallas kernel
+    (see the section comment): the same cycle, word for word, with the
+    loops inside the kernel.  ``interpret=True`` runs it in the Pallas
+    interpreter (the CPU tests)."""
+    B, W = mem0.shape
+    n_inv, _, LI = li_stack.shape
+    XW, PL, CW, OW = _vmem_layout(P, RF, LI)
+    NW, KR = XW + OW, XW + PL
+    B8, Wp, G = -(-B // 8) * 8, _lanes(W), -(-n_inv // 8)
+    L = c["load_lanes"].shape[1]
+    S = c["store_lanes"].shape[1]
+    i32 = jnp.int32
+
+    row, crow, col = _mux_maps(P, RF, LI)
+    oh = _one_hot(c["port_idx"], row, col, KR, NW)
+    ohc = _one_hot(c["port_idx"], crow, col, CW, NW)
+
+    def lanes(x, width):          # [II, n] -> [II, 1, width], zero-padded
+        x = x.astype(i32)
+        return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))[:, None, :]
+
+    wmask = lanes(jnp.concatenate(
+        [c["xo_mask"].reshape(II, P * 4), c["rf_mask"].reshape(II, P * RF)],
+        axis=1), XW)
+    fb = lanes(jnp.swapaxes(c["force_before"], 1, 2).reshape(II, 3 * P), OW)
+    fv = lanes(_wrap(jnp.swapaxes(c["force_val"], 1, 2).reshape(II, 3 * P)
+                     .astype(i32), bits), OW)
+    opc = lanes(c["op"], PL)
+    imm = lanes(_wrap(c["imm"].astype(i32), bits), CW)
+    li = _wrap(li_stack.reshape(n_inv, P * LI).astype(i32), bits)
+    li = jnp.pad(li, ((0, G * 8 - n_inv), (P, CW - P - P * LI)))
+    li = li.reshape(G, 8, CW)
+    mem = jnp.pad(mem0.astype(i32), ((0, B8 - B), (0, Wp - W)))
+    smem = [c[k].astype(i32) for k in ("load_lanes", "store_lanes",
+                                       "mem_off", "mem_words",
+                                       "valid_start")]
+    window = n_iters * II
+
+    def kernel(oh_ref, ohc_ref, imm_ref, li_ref, wm_ref, fb_ref, fv_ref,
+               op_ref, mem_in, lds_ref, sts_ref, moff_ref, mw_ref, vs_ref,
+               mem_ref, vc_ref):
+        mem_ref[...] = mem_in[...]
+        lane = jax.lax.broadcasted_iota(i32, (B8, PL), 1)
+        word = jax.lax.broadcasted_iota(i32, (B8, Wp), 1)
+        sub = jax.lax.broadcasted_iota(i32, (8, CW), 0)
+
+        def lane_of(x, q):        # [B8, PL] -> lane q as [B8, 1]
+            return jnp.sum(jnp.where(lane == q, x, 0), axis=1,
+                           keepdims=True)
+
+        def address(a, s, q):     # clipped bank-relative word of lane q
+            return moff_ref[s, q] + jnp.clip(lane_of(a, q), 0,
+                                             mw_ref[s, q] - 1)
+
+        def invocation(i, carry):
+            li_row = jnp.sum(jnp.where(sub == i % 8, li_ref[i // 8], 0),
+                             axis=0, keepdims=True)           # [1, CW]
+
+            def constants(s, carry):
+                cblk = jnp.broadcast_to(imm_ref[s] + li_row, (8, CW))
+                vc_ref[s] = _mux(cblk, ohc_ref[s], bits)[0:1]
+                return carry
+
+            jax.lax.fori_loop(0, II, constants, 0)
+
+            def store(t, a, b):
+                # the STOREs of cycle t, each under its validity window
+                s = t % II
+                for k in range(S):
+                    p = sts_ref[s, k]
+                    q = jnp.maximum(p, 0)
+                    vs = vs_ref[s, q]
+
+                    @pl.when((p >= 0) & (t >= vs) & (t < vs + window))
+                    def _store():
+                        mem_ref[...] = jnp.where(
+                            word == address(a, s, q), lane_of(b, q),
+                            mem_ref[...])
+
+            def cycle(t, st):
+                # cycle t's mux and ALU beside cycle t-1's memory work:
+                # cycle t-1's LOADs read the image after the stores of t-2
+                # and fill the pipeline register the FU takes at the end
+                # of cycle t; its STOREs end the step, before the next
+                # step runs cycle t's LOADs
+                x, fu, ldp, fl, a_prev, b_prev = st
+                s = t % II
+                v = _mux(jnp.concatenate([x, fu], axis=1), oh_ref[s],
+                         bits) + vc_ref[s]                    # [B8, NW]
+                ops = jnp.where(t < fb_ref[s], fv_ref[s], v[:, XW:])
+                a = ops[:, :PL]
+                b = pltpu.roll(ops, OW - P, 1)[:, :PL]
+                p3 = pltpu.roll(ops, OW - 2 * P, 1)[:, :PL]
+                o = op_ref[s]                                 # [1, PL]
+                res = _alu(o, a, b, p3, bits)
+
+                s_prev = (t + II - 1) % II
+                image = mem_ref[...]
+                for k in range(L):
+                    p = jnp.where(t >= 1, lds_ref[s_prev, k], -1)
+                    q = jnp.maximum(p, 0)
+                    val = jnp.sum(jnp.where(word == address(a_prev, s_prev,
+                                                            q),
+                                            image, 0), axis=1, keepdims=True)
+                    ldp = jnp.where(lane == p, val, ldp)
+
+                is_load = o == OPC_LOAD
+                is_store = o == OPC_STORE
+                fu_next = jnp.where(
+                    fl != 0, ldp,
+                    jnp.where((o != OPC_NONE) & ~is_load & ~is_store,
+                              res, fu))
+                fl_next = jnp.broadcast_to(is_load.astype(i32), (B8, PL))
+                x_next = jnp.where(wm_ref[s] != 0, v[:, :XW], x)
+
+                @pl.when(t >= 1)
+                def _():
+                    store(t - 1, a_prev, b_prev)
+
+                return x_next, fu_next, ldp, fl_next, a, b
+
+            zeros = jnp.zeros((B8, PL), i32)
+            st = jax.lax.fori_loop(0, n_cycles, cycle,
+                                   (jnp.zeros((B8, XW), i32), zeros, zeros,
+                                    zeros, zeros, zeros))
+            store(n_cycles - 1, st[4], st[5])
+            return carry
+
+        jax.lax.fori_loop(0, n_inv, invocation, 0)
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    limit = _vmem_bytes(B, W, P, RF, LI, II, n_inv) + (16 << 20)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B8, Wp), i32),
+        in_specs=[vmem] * 9 + [smem_spec] * 5,
+        out_specs=vmem,
+        scratch_shapes=[pltpu.VMEM((II, 1, NW), i32)],
+        input_output_aliases={8: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
+        interpret=interpret,
+        name="morpher_sim_cycles",
+    )(oh, ohc, imm, li, wmask, fb, fv, opc, mem, *smem)
+    return out[:B, :W].astype(mem0.dtype)
+
+
 def _build_batched(sig: simcache.SimSignature):
     """Compile-on-demand builder for one batched-simulator signature,
     jitted with the batched image buffer donated so per-seed images are
@@ -438,6 +730,10 @@ def _build_batched(sig: simcache.SimSignature):
                   n_iters=sig.n_iters, n_cycles=sig.n_cycles)
 
     def morpher_sim(c, mem0, li_stack):
+        B, W = mem0.shape
+        n_inv, _, LI = li_stack.shape
+        if _body(False, B, W, sig.P, sig.RF, LI, sig.II, n_inv) == "vmem":
+            return _vmem_sim(c, mem0, li_stack, **static)
         return _sim_body(c, mem0, li_stack, **static)
 
     def morpher_sim_multi(c, mem0, li_stack):
@@ -461,15 +757,19 @@ def _launch(sig: simcache.SimSignature, planes: Dict, mem: np.ndarray,
     ``morpher.sim.launch`` span with the launch's counters as attrs:
     scan steps launched (bucketed cycles x invocations), rows (bucketed
     batch) and row-steps, the real rows and row-steps among them, whether
-    the body took the pre-tiled streams, and whether this launch built
-    the executable."""
+    which body ran (``_body``: the VMEM kernel or the scan), whether the
+    scan took the pre-tiled streams (never on the kernel), and whether
+    this launch built the executable."""
     n_inv = int(li_stack.shape[0])
     steps = sig.n_cycles * n_inv
+    body = _body(sig.multi, sig.batch, mem.shape[-1], sig.P, sig.RF,
+                 li_stack.shape[-1], sig.II, n_inv)
     with obs.span("morpher.sim.launch", multi=sig.multi, invocations=n_inv,
                   steps=steps, rows=sig.batch, real_rows=real_rows,
                   row_steps=steps * sig.batch,
-                  real_row_steps=real_row_steps,
-                  pretiled=_pretiled(planes, sig.II, sig.n_cycles),
+                  real_row_steps=real_row_steps, body=body,
+                  pretiled=(body == "scan"
+                            and _pretiled(planes, sig.II, sig.n_cycles)),
                   built=False) as attrs:
         def build():
             attrs["built"] = True
@@ -577,19 +877,20 @@ def stack_signature(cfg: SimConfig, n_iters: int,
 def _stack_planes(per: List[Dict[str, np.ndarray]],
                   reps: List[int]) -> Dict[str, np.ndarray]:
     """Stack per-config host planes into ``[B, II, ...]`` rows, repeating
-    each config for its memory-image count.  Store-lane tables pad to the
+    each config for its memory-image count.  Lane tables pad to the
     group-wide lane count with -1 (dead lanes); value planes promote to
     the group's common dtype — both value-preserving, so stacked rows
     decode exactly as their single-config originals."""
-    S = max(p["store_lanes"].shape[1] for p in per)
+    width = {k: max(p[k].shape[1] for p in per)
+             for k in ("store_lanes", "load_lanes")}
     out: Dict[str, np.ndarray] = {}
     for k in per[0]:
         arrs = []
         for p, rep in zip(per, reps):
             a = p[k]
-            if k == "store_lanes" and a.shape[1] < S:
+            if k in width and a.shape[1] < width[k]:
                 a = np.concatenate(
-                    [a, np.full((a.shape[0], S - a.shape[1]), -1,
+                    [a, np.full((a.shape[0], width[k] - a.shape[1]), -1,
                                 dtype=a.dtype)], axis=1)
             arrs.append(np.repeat(a[None], rep, axis=0))
         dtype = np.result_type(*(a.dtype for a in arrs))

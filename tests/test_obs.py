@@ -3,8 +3,8 @@ simulator's and the oracle's device programs.
 
 Spans nest by thread, share the id of their outermost span as ``root`` and
 stay within the ring's bound; every simulator launch records what it
-launched (scan steps, rows, real row-steps, tiling, whether it built the
-executable); one ``verify_batch`` opens the spans of its layers under one
+launched (scan steps, rows, real row-steps, which body ran, tiling,
+whether it built the executable); one ``verify_batch`` opens the spans of its layers under one
 root; and the lowered programs carry stable module names."""
 import re
 
@@ -92,13 +92,15 @@ def test_launch_counters_match_shapes(ck, monkeypatch):
     real = cfg.n_cycles(ck.mapped_iters)
     steps = simcache.bucket_cycles(real) * n_inv
     banks = [generate_test_data(ck.spec, s).init_banks for s in (1, 2, 3)]
+    body = simulator._body(False, 4, cfg.total_words, cfg.P, cfg.RF,
+                           max(1, cfg.LI), cfg.II, n_inv)
     simcache.clear()
     tiled = ck.run_batch(banks)
     first = _last("morpher.sim.launch")["attrs"]
     assert first == {"multi": False, "invocations": n_inv, "steps": steps,
                      "rows": 4, "real_rows": 3, "row_steps": steps * 4,
-                     "real_row_steps": real * n_inv * 3, "pretiled": True,
-                     "built": True}
+                     "real_row_steps": real * n_inv * 3, "body": body,
+                     "pretiled": True, "built": True}
     ck.run_batch(banks)
     assert _last("morpher.sim.launch")["attrs"]["built"] is False
 
@@ -109,6 +111,32 @@ def test_launch_counters_match_shapes(ck, monkeypatch):
     attrs = _last("morpher.sim.launch")["attrs"]
     assert attrs["pretiled"] is False and attrs["built"] is True
     for a, b in zip(tiled, untiled):
+        for bank in a:
+            np.testing.assert_array_equal(a[bank], b[bank])
+    simcache.clear()
+
+
+def test_launch_body_follows_dispatch(ck, monkeypatch):
+    """On a TPU backend the launch runs the VMEM kernel (here in the
+    Pallas interpreter) and says so; the scan's tiling flag is off."""
+    import functools
+
+    import jax
+    cfg, n_inv = ck.cfg, len(ck.invocations)
+    banks = [generate_test_data(ck.spec, s).init_banks for s in (1, 2, 3)]
+    simcache.clear()
+    scan = ck.run_batch(banks)
+    assert _last("morpher.sim.launch")["attrs"]["body"] == "scan"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(simulator, "_vmem_sim", functools.partial(
+        simulator._vmem_sim, interpret=True))
+    assert simulator._body(False, 4, cfg.total_words, cfg.P, cfg.RF,
+                           max(1, cfg.LI), cfg.II, n_inv) == "vmem"
+    simcache.clear()
+    vmem = ck.run_batch(banks)
+    attrs = _last("morpher.sim.launch")["attrs"]
+    assert (attrs["body"], attrs["pretiled"]) == ("vmem", False)
+    for a, b in zip(scan, vmem):
         for bank in a:
             np.testing.assert_array_equal(a[bank], b[bank])
     simcache.clear()
@@ -125,6 +153,7 @@ def test_multi_launch_counters(ck):
     attrs = _last("morpher.sim.launch")["attrs"]
     rows = simcache.bucket_rows(5)
     assert attrs["multi"] is True and attrs["steps"] == steps
+    assert attrs["body"] == "scan"
     assert (attrs["rows"], attrs["real_rows"]) == (rows, 5)
     assert attrs["row_steps"] == steps * rows
     assert attrs["real_row_steps"] == real * n_inv * 5

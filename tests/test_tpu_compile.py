@@ -23,7 +23,8 @@ from repro.core import simcache
 from repro.core.dfg import Op
 from repro.core.kernels_lib import table1_kernels
 from repro.core.refexec import _lowered
-from repro.core.simulator import _build_batched, _host_planes, _stack_planes
+from repro.core.simulator import (_body, _build_batched, _host_planes,
+                                  _stack_planes)
 from repro.core.toolchain import Toolchain
 from repro.kernels.gemm_os.kernel import gemm_os_pallas
 from repro.models.zoo import build_model
@@ -70,8 +71,9 @@ def _shapes(tree, sharding):
             sharding=sharding), tree)
 
 
-def test_simulator_paper_gemm_batch8(one_chip, chip_backend, paper_gemm):
-    ck = paper_gemm
+def _compile_batch8(ck, sharding):
+    """The batch-8 executable of a compiled kernel, as ``simulate_batch``
+    launches it, compiled for the described chip; its text."""
     cfg, n_inv = ck.cfg, len(ck.invocations)
     sig = simcache.SimSignature(
         II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=ck.mapped_iters,
@@ -79,10 +81,29 @@ def test_simulator_paper_gemm_batch8(one_chip, chip_backend, paper_gemm):
         batch=8)
     mem = np.zeros((8, cfg.total_words), np.int16)
     li = np.zeros((n_inv, cfg.P, max(1, cfg.LI)), np.int32)
-    compiled = _build_batched(sig).lower(
-        _shapes(_host_planes(cfg), one_chip),
-        *_shapes((mem, li), one_chip)).compile()
-    assert "input_output_alias" in compiled.as_text()   # image donated
+    assert _body(False, 8, cfg.total_words, cfg.P, cfg.RF, max(1, cfg.LI),
+                 cfg.II, n_inv) == "vmem"         # fits the VMEM budget
+    return _build_batched(sig).lower(
+        _shapes(_host_planes(cfg), sharding),
+        *_shapes((mem, li), sharding)).compile().as_text()
+
+
+def test_simulator_paper_gemm_batch8(one_chip, chip_backend, paper_gemm):
+    text = _compile_batch8(paper_gemm, one_chip)
+    assert "tpu_custom_call" in text                    # the VMEM kernel
+    assert "input_output_alias" in text                 # image donated
+
+
+@pytest.mark.parametrize("name,invocations,steps", [
+    ("CONV", 11532, 11532 * 36), ("GEMM-U-C", 1, 212992)])
+def test_simulator_paper_long_launches(one_chip, chip_backend, name,
+                                       invocations, steps):
+    ck = Toolchain(cache_dir="").compile(table1_kernels()[name])
+    n_cycles = simcache.bucket_cycles(ck.cfg.n_cycles(ck.mapped_iters))
+    assert (len(ck.invocations), len(ck.invocations) * n_cycles) == \
+        (invocations, steps)
+    text = _compile_batch8(ck, one_chip)
+    assert "tpu_custom_call" in text and "input_output_alias" in text
 
 
 def test_simulator_stacked_multi(one_chip, chip_backend, paper_gemm):
@@ -98,8 +119,10 @@ def test_simulator_stacked_multi(one_chip, chip_backend, paper_gemm):
         batch=8, LI=LI, multi=True)
     mem = np.zeros((8, cfg.total_words), np.int16)
     li = np.zeros((n_inv, 8, cfg.P, LI), np.int32)
-    _build_batched(sig).lower(_shapes(planes, one_chip),
-                              *_shapes((mem, li), one_chip)).compile()
+    text = _build_batched(sig).lower(
+        _shapes(planes, one_chip), *_shapes((mem, li), one_chip)).compile() \
+        .as_text()
+    assert "tpu_custom_call" not in text        # multi keeps the scan
 
 
 def test_refexec_oracle_table1_conv(one_chip, chip_backend):
