@@ -135,6 +135,12 @@ class CGRAArch:
     def pes_of_bank(self, bank_id: int) -> Tuple[int, ...]:
         return self.bank(bank_id).pes
 
+    def cluster_banks(self) -> List[List[int]]:
+        """Bank ids per cluster, in declaration order: the banks whose bus
+        PEs all lie inside the cluster."""
+        return [[b.id for b in self.banks if set(b.pes) <= set(cluster)]
+                for cluster in self.clusters]
+
     def supports(self, p: int, op: Op) -> bool:
         ops = self.per_pe_ops.get(p, self.fu_ops)
         if op in MEM_OPS and p not in self.mem_pes:

@@ -11,6 +11,11 @@ Pipeline per candidate II (starting at MII, escalating on failure):
   4. register-file assignment: residency intervals from the routes are
      coloured onto the R physical registers per PE (cyclic-interval greedy).
 
+A DFG that splits into shares, each a set of connected components using
+the banks of one cluster only (one layer spread over the clusters,
+``frontend/layers.py``), maps one cluster at a time at a common II and the
+shares are joined (``_map_parts``).
+
 MII = max(ResMII, RecMII):
   ResMII = max( ceil(#ops / #PEs), max_bank #accesses(bank),
                 ceil(#mem-ops / #mem-PEs) )
@@ -27,7 +32,7 @@ from concurrent.futures import TimeoutError as _FuturesTimeout
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from .adl import CGRAArch
+from .adl import CGRAArch, MemBank
 from .dfg import DFG, Node, Op, Operand, latency
 from .layout import DataLayout
 from .mrrg import F, R, Route, Usage, commit_route, release_route, route_value
@@ -295,7 +300,9 @@ class _DFGInfo:
     height: Dict[int, int]                     # dist-0 DAG height
     cyc_ids: List[int]                         # priority prefix (cycles)
     rest: List[int]                            # acyclic ids, dfg.nodes order
-    self_loop: Set[int]                        # dist>0 self-loop sources
+    self_loop: Set[int]                        # dist>0 self-loop nodes
+    sink_loop: Set[int]                        # self-loops fed by computed
+                                               # values (running sums)
     multi_cycle: Set[int]                      # members of len>1 SCCs
     comps: List[List[int]]                     # len>1 SCCs
     rank: List[int]                            # condensation longest-path
@@ -314,6 +321,13 @@ def _dfg_info(dfg: DFG) -> _DFGInfo:
 
     self_loop = {src for src, dst, _s, o in dfg.data_edges()
                  if src == dst and o.dist > 0}
+    # a self-loop that also reads a computed value (a running sum) ends a
+    # chain, like a multi-node recurrence; one that reads only constants
+    # and live-ins (an induction variable) starts one
+    sink_loop = {v for v in self_loop
+                 if any(o.dist == 0 and dfg.nodes[o.src].op not in
+                        (Op.CONST, Op.LIVEIN)
+                        for o in dfg.nodes[v].operands)}
     sccs = _sccs(dfg)
     cyc_comps = [c for c in sccs
                  if len(c) > 1 or (len(c) == 1 and c[0] in self_loop)]
@@ -366,6 +380,7 @@ def _dfg_info(dfg: DFG) -> _DFGInfo:
                                                         -len(comps[ci])))
     return _DFGInfo(edges=_edges_with_memdeps(dfg), cons=cons, height=height,
                     cyc_ids=cyc_ids, rest=rest, self_loop=self_loop,
+                    sink_loop=sink_loop,
                     multi_cycle=multi_cycle, comps=comps, rank=rank,
                     order_c=order_c)
 
@@ -479,7 +494,8 @@ def _try_map(dfg: DFG, arch: CGRAArch, II: int, seed: int,
                 o.src in place for o in n.operands if o.src != v) and not any(
                 c in place for c, _ in cons[v] if c != v):
             # first node of its recurrence: leave feeder room
-            t_lo += margin if v in multi_cycle else self_margin
+            t_lo += (margin if v in multi_cycle or v in info.sink_loop
+                     else self_margin)
         t_hi = t_lo + window_factor * II - 1
         succ_bound = False
         for slot, opnd in enumerate(n.operands):
@@ -778,6 +794,255 @@ def _portfolio_worker(payload: str) -> Optional[str]:
     return json.dumps(mapping.to_json_dict())
 
 
+# ------------------------------------------------ partitions over clusters
+@dataclass
+class _Part:
+    """One cluster's share of a partitioned DFG: its nodes, the cluster as
+    a fabric of its own (``local`` PE ids), and the global id of each
+    local PE."""
+    dfg: DFG
+    arch: CGRAArch
+    to_global: List[int]
+    bank_of: Dict[int, int]
+
+
+def _components(dfg: DFG) -> List[List[int]]:
+    """Weakly connected components over data edges and memory deps."""
+    parent = {v: v for v in dfg.nodes}
+
+    def find(v: int) -> int:
+        while parent[v] != v:
+            parent[v] = parent[parent[v]]
+            v = parent[v]
+        return v
+
+    pairs = [(s, d) for s, d, _sl, _o in dfg.data_edges()]
+    pairs += [(md.src, md.dst) for md in dfg.mem_deps]
+    for s, d in pairs:
+        parent[find(s)] = find(d)
+    comps: Dict[int, List[int]] = {}
+    for v in dfg.nodes:
+        comps.setdefault(find(v), []).append(v)
+    return list(comps.values())
+
+
+def _cluster_arch(arch: CGRAArch, ci: int) -> Optional[Tuple[CGRAArch,
+                                                             List[int]]]:
+    """Cluster ``ci`` as a fabric of its own, with the global id of each of
+    its PEs; None unless the cluster is a full rectangle of the grid."""
+    pes = sorted(arch.clusters[ci])
+    rcs = [arch.pe_rc(p) for p in pes]
+    r0, c0 = min(r for r, _ in rcs), min(c for _, c in rcs)
+    rows = max(r for r, _ in rcs) - r0 + 1
+    cols = max(c for _, c in rcs) - c0 + 1
+    if rows * cols != len(pes):
+        return None
+    to_global = [arch.pe_id(r0 + p // cols, c0 + p % cols)
+                 for p in range(rows * cols)]
+    local = {g: p for p, g in enumerate(to_global)}
+    banks = [MemBank(b.id, b.size_bytes, tuple(local[p] for p in b.pes))
+             for b in arch.banks if set(b.pes) <= set(pes)]
+    sub = CGRAArch(
+        name=f"{arch.name}/cluster{ci}", rows=rows, cols=cols,
+        datapath_bits=arch.datapath_bits, regfile_size=arch.regfile_size,
+        livein_regs=arch.livein_regs, rf_write_ports=arch.rf_write_ports,
+        banks=banks, fu_ops=arch.fu_ops,
+        per_pe_ops={local[p]: ops for p, ops in arch.per_pe_ops.items()
+                    if p in local},
+        clusters=[list(range(rows * cols))])
+    return sub, to_global
+
+
+def _cluster_parts(dfg: DFG, arch: CGRAArch,
+                   bank_of: Dict[int, int]) -> Optional[List[_Part]]:
+    """The DFG's share of each cluster, when it splits that way: every
+    connected component reaches banks of one cluster only, and the
+    components span two clusters or more.  None otherwise (a DFG with one
+    component, a component without memory nodes, or a cluster that is not
+    a rectangle), and the whole fabric is mapped as one."""
+    if len(arch.clusters) < 2:
+        return None
+    cluster_of_bank = {b: ci for ci, banks in enumerate(arch.cluster_banks())
+                       for b in banks}
+    by_cluster: Dict[int, List[int]] = {}
+    for comp in _components(dfg):
+        cis = {cluster_of_bank.get(bank_of[v]) for v in comp if v in bank_of}
+        if len(cis) != 1 or None in cis:
+            return None
+        by_cluster.setdefault(cis.pop(), []).extend(comp)
+    if len(by_cluster) < 2:
+        return None
+    parts = []
+    for ci in sorted(by_cluster):
+        got = _cluster_arch(arch, ci)
+        if got is None:
+            return None
+        sub_arch, to_global = got
+        nodes = set(by_cluster[ci])
+        sub = DFG(f"{dfg.name}/cluster{ci}",
+                  nodes={v: n for v, n in dfg.nodes.items() if v in nodes},
+                  mem_deps=[md for md in dfg.mem_deps if md.src in nodes])
+        parts.append(_Part(sub, sub_arch, to_global,
+                           {v: b for v, b in bank_of.items() if v in nodes}))
+    return parts
+
+
+def _isomorphic(a: DFG, b: DFG, nmap: Dict[int, int],
+                bmap: Dict[int, int]) -> bool:
+    """Whether ``nmap`` carries DFG ``a`` onto ``b`` node for node, with a's
+    banks renamed by ``bmap`` (live-in names may differ)."""
+    for u, v in nmap.items():
+        nu, nv = a.nodes[u], b.nodes[v]
+        if (nu.op != nv.op or nu.imm != nv.imm
+                or len(nu.operands) != len(nv.operands)
+                or any(nmap[x.src] != y.src or x.dist != y.dist
+                       or x.init != y.init
+                       for x, y in zip(nu.operands, nv.operands))):
+            return False
+        if nu.is_mem and bmap.get(int(nu.array[4:])) != int(nv.array[4:]):
+            return False
+    return ({(nmap[m.src], nmap[m.dst], m.dist) for m in a.mem_deps}
+            == {(m.src, m.dst, m.dist) for m in b.mem_deps})
+
+
+def _reflection(a: _Part, b: _Part):
+    """How a mapping of share ``a`` carries onto share ``b``: a symmetry of
+    the grid (the identity, or rows and/or columns mirrored) that takes a's
+    cluster onto b's, banks onto banks, and the node bijection that takes
+    a's DFG onto b's (the shares of one layer are traced alike, so the k-th
+    node of one is the k-th of the other).  Returns (PE map, direction map,
+    bank map, node map, live-in name map), or None."""
+    A, B = a.arch, b.arch
+    if ((A.rows, A.cols) != (B.rows, B.cols)
+            or len(a.dfg.nodes) != len(b.dfg.nodes)):
+        return None
+    nmap = dict(zip(sorted(a.dfg.nodes), sorted(b.dfg.nodes)))
+    banks_b = {frozenset(bk.pes): bk for bk in B.banks}
+    for fr, fc in ((0, 0), (0, 1), (1, 0), (1, 1)):
+        pmap = [B.pe_id(A.rows - 1 - r if fr else r,
+                        A.cols - 1 - c if fc else c)
+                for r, c in map(A.pe_rc, range(A.n_pes))]
+        bmap = {}
+        for bk in A.banks:
+            tgt = banks_b.get(frozenset(pmap[p] for p in bk.pes))
+            if tgt is not None and tgt.size_bytes == bk.size_bytes:
+                bmap[bk.id] = tgt.id
+        if (len(bmap) == len(A.banks)
+                and all(A.per_pe_ops.get(p) == B.per_pe_ops.get(pmap[p])
+                        for p in range(A.n_pes))
+                and _isomorphic(a.dfg, b.dfg, nmap, bmap)):
+            # DIRS is N, E, S, W: mirrored rows swap N and S, columns E, W
+            dmap = (2 if fr else 0, 3 if fc else 1, 0 if fr else 2,
+                    1 if fc else 3)
+            names = {a.dfg.nodes[u].livein: b.dfg.nodes[v].livein
+                     for u, v in nmap.items()
+                     if a.dfg.nodes[u].op == Op.LIVEIN}
+            return pmap, dmap, bmap, nmap, names
+    return None
+
+
+def _reflect(got, maps, arch: CGRAArch, II: int):
+    """A share's (placement, routes, usage) and registers moved by ``maps``
+    = (PE map, direction map, bank map, node map, live-in name map), as
+    ``_reflection`` gives them, onto ``arch``; a map that is None keeps its
+    names."""
+    (place, routes, usage), regs = got
+    pmap, dmap, bmap, nmap, names = maps
+    dmap = dmap or (0, 1, 2, 3)
+
+    def node(v):
+        return v if nmap is None else nmap[v]
+
+    def key(k):
+        if k[0] == "bank":
+            return k if bmap is None else ("bank", bmap[k[1]], k[2])
+        if k[0] == "xo":
+            return ("xo", pmap[k[1]], dmap[k[2]], k[3])
+        return (k[0], pmap[k[1]]) + tuple(k[2:])
+
+    def inst(i):                      # (value, t), or (live-in name, -1)
+        if isinstance(i[0], str):
+            return (i[0] if names is None else names[i[0]], i[1])
+        return (node(i[0]), i[1])
+
+    out = Usage(arch, II)
+    for k, insts in usage.map.items():
+        for i in insts:
+            out.add(key(k), inst(i))
+    return ({node(v): (pmap[pe], t) for v, (pe, t) in place.items()},
+            {(node(s), node(d), sl): Route(
+                node(r.value), pmap[r.src_pe], r.t_src, pmap[r.dst_pe],
+                r.t_dst, steps=[(kd, pmap[pe], t) for kd, pe, t in r.steps],
+                uses=[(key(k), inst(i)) for k, i in r.uses])
+             for (s, d, sl), r in routes.items()},
+            out), {(pmap[pe], node(v), t): reg
+                   for (pe, v, t), reg in regs.items()}
+
+
+def _map_parts(dfg: DFG, arch: CGRAArch, parts: List[_Part], mii: int,
+               parts_mii: Dict[str, int], opt: MapperOptions,
+               deadline: Optional[float]) -> Mapping:
+    """Map each cluster's share onto its cluster at one common II, escalating
+    from the MII, and join the shares into one mapping of the whole fabric.
+    The shares use disjoint PEs, banks and wires, so any union of
+    per-cluster mappings at one II is a mapping of the whole DFG.  A share
+    that is the image of an earlier one under a symmetry of the fabric (the
+    paper's 8x8 target mirrors its left clusters onto its right ones) takes
+    that share's mapping, reflected, and is not searched again."""
+    import time as _time
+    infos = [_dfg_info(p.dfg) for p in parts]
+    source: List[Optional[Tuple[int, tuple]]] = [None] * len(parts)
+    for j in range(len(parts)):
+        for i in range(j):
+            ref = source[i] is None and _reflection(parts[i], parts[j])
+            if ref:
+                source[j] = (i, ref)
+                break
+    start = max(mii, opt.ii_start or 0)
+    for II in range(start, opt.ii_max + 1):
+        got: list = []
+        for part, info, src in zip(parts, infos, source):
+            if src is not None:
+                got.append(_reflect(got[src[0]], src[1], part.arch, II))
+                continue
+            asap = _asap(part.dfg, II, info.edges)
+            for seed in opt.seeds:
+                if deadline is not None and _time.time() > deadline:
+                    raise MapError(f"{dfg.name}: time budget exhausted at "
+                                   f"II={II} (MII={mii})")
+                m = _try_map(part.dfg, part.arch, II, seed, part.bank_of,
+                             info, asap)
+                regs = m and _color_registers(part.arch, II, m[1])
+                if regs is not None:
+                    got.append((m, regs))
+                    break
+            else:
+                break
+        if len(got) < len(parts):
+            continue
+        place: Dict[int, Tuple[int, int]] = {}
+        routes: Dict[Tuple[int, int, int], Route] = {}
+        usage = Usage(arch, II)
+        reg_assign: Dict[Tuple[int, int, int], int] = {}
+        for part, share in zip(parts, got):
+            (pl, rt, us), regs = _reflect(
+                share, (part.to_global, None, None, None, None), arch, II)
+            place.update(pl)
+            routes.update(rt)
+            reg_assign.update(regs)
+            for key, insts in us.map.items():
+                for inst in insts:
+                    usage.add(key, inst)
+        bank_of = {v: b for p in parts for v, b in p.bank_of.items()}
+        return Mapping(dfg=dfg, arch=arch, II=II, mii=mii,
+                       mii_parts=parts_mii, place=place, routes=routes,
+                       usage=usage, reg_assign=reg_assign,
+                       lireg_assign=_assign_liregs(arch, dfg, place),
+                       bank_of=bank_of)
+    raise MapError(f"{dfg.name}: no mapping found with II <= {opt.ii_max} "
+                   f"(MII={mii}, parts={parts_mii})")
+
+
 def map_kernel_opts(dfg: DFG, arch: CGRAArch, layout: DataLayout,
                     options: Optional[MapperOptions] = None, *,
                     portfolio: Optional[bool] = None) -> Mapping:
@@ -804,6 +1069,9 @@ def map_kernel_opts(dfg: DFG, arch: CGRAArch, layout: DataLayout,
     dfg.validate()
     bank_of = _bank_of_nodes(dfg, layout)
     mii, parts = compute_mii(dfg, arch, bank_of)
+    shares = _cluster_parts(dfg, arch, bank_of)
+    if shares is not None:
+        return _map_parts(dfg, arch, shares, mii, parts, opt, deadline)
     info = _dfg_info(dfg)
     start = max(mii, opt.ii_start or 0)
     # portfolio=True races unconditionally; auto mode races a round only

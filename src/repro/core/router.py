@@ -45,7 +45,8 @@ class RouterTables:
     """Per-(topology, II) packed lookup tables shared by every ``Usage``."""
 
     __slots__ = ("P", "II", "fuout_base", "xo_base", "regpool_base",
-                 "wr_base", "bank_base", "lireg_base", "n_resources",
+                 "wr_base", "bank_base", "bank_pos", "lireg_base",
+                 "n_resources",
                  "nbrs", "dist", "cap_regpool", "cap_wr", "cap_lireg")
 
     def __init__(self, arch: CGRAArch, II: int):
@@ -57,6 +58,8 @@ class RouterTables:
         self.regpool_base = 6 * n
         self.wr_base = 7 * n
         self.bank_base = 8 * n
+        # banks pack by declaration position: ids may be any integers
+        self.bank_pos = {b.id: i for i, b in enumerate(arch.banks)}
         self.lireg_base = 8 * n + len(arch.banks) * II
         self.n_resources = self.lireg_base + P
         self.nbrs: List[Tuple[Tuple[int, int], ...]] = [
@@ -83,7 +86,7 @@ class RouterTables:
         if k == "wr":
             return self.wr_base + key[1] * II + key[2]
         if k == "bank":
-            return self.bank_base + key[1] * II + key[2]
+            return self.bank_base + self.bank_pos[key[1]] * II + key[2]
         if k == "lireg":
             return self.lireg_base + key[1]
         raise KeyError(key)
@@ -95,7 +98,8 @@ _tables_cache: Dict[Tuple, RouterTables] = {}
 def router_tables(arch: CGRAArch, II: int) -> RouterTables:
     # everything the tables read off the arch, nothing else
     ck = (II, arch.rows, arch.cols, arch.torus, arch.regfile_size,
-          arch.rf_write_ports, arch.livein_regs, len(arch.banks))
+          arch.rf_write_ports, arch.livein_regs,
+          tuple(b.id for b in arch.banks))
     t = _tables_cache.get(ck)
     if t is None:
         t = _tables_cache[ck] = RouterTables(arch, II)

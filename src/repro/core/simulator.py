@@ -460,6 +460,10 @@ def _sim_body(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
 #     c at lanes k*P + pe ].  A column has at most one nonzero term and the
 #     values enter as 8-bit pieces that bf16 holds exactly, so the f32
 #     accumulation is exact; an unconfigured port has no term and reads 0;
+#   * each slot's one-hot planes stay in VMEM where they fit the budget; a
+#     fabric whose planes do not (64 PEs from II 16 on) keeps each slot's
+#     source rows instead and builds the cycle's planes in the kernel
+#     (``_vmem_planes``);
 #   * LOADs and STOREs touch only the slot's load / store lanes (SMEM
 #     tables), by iota compares over the image row; a gated-off store
 #     writes nothing;
@@ -483,27 +487,46 @@ def _vmem_layout(P: int, RF: int, LI: int) -> Tuple[int, int, int, int]:
 
 
 def _vmem_bytes(B: int, W: int, P: int, RF: int, LI: int, II: int,
-                n_inv: int) -> int:
+                n_inv: int, resident: bool = True) -> int:
     """VMEM the kernel body holds for these shapes: the image in and out
-    plus two image-sized temporaries, the one-hot mux planes (bf16), the
-    live-in rows, and the [II, 1, N] slot planes and scratch, whose single
-    row the (8, 128) tiling pads to 8."""
+    plus two image-sized temporaries, the mux planes, the live-in rows, and
+    the [II, 1, N] slot planes and scratch, whose single row the (8, 128)
+    tiling pads to 8.  The mux planes are the one-hot planes of every slot
+    (bf16) when ``resident``, else the source-row tables they are built
+    from and one plane built in the kernel with its temporaries."""
     XW, PL, CW, OW = _vmem_layout(P, RF, LI)
     NW = XW + OW
     image = -(-B // 8) * 8 * _lanes(W) * 4
-    return (4 * image + II * (XW + PL + CW) * NW * 2
-            + -(-n_inv // 8) * 8 * CW * 4
+    rows = XW + PL + CW
+    planes = (II * rows * NW * 2 if resident
+              else rows * NW * 6 + II * 8 * 4 * 2 * NW)
+    return (4 * image + planes + -(-n_inv // 8) * 8 * CW * 4
             + II * 8 * 4 * (XW + 2 * OW + PL + CW + NW))
+
+
+def _vmem_planes(B: int, W: int, P: int, RF: int, LI: int, II: int,
+                 n_inv: int) -> str:
+    """How the VMEM body would hold the mux planes of these shapes:
+    ``"resident"`` (every slot's one-hot plane in VMEM) where that fits
+    ``_VMEM_BUDGET``, else ``"built"`` (each cycle's plane built in the
+    kernel from its slot's source rows, for fabrics whose planes do not
+    fit) where that fits, else ``""``."""
+    for mode in ("resident", "built"):
+        if _vmem_bytes(B, W, P, RF, LI, II, n_inv,
+                       mode == "resident") <= _VMEM_BUDGET:
+            return mode
+    return ""
 
 
 def _body(multi: bool, B: int, W: int, P: int, RF: int, LI: int, II: int,
           n_inv: int) -> str:
     """Which body a launch of these shapes runs: ``"vmem"`` (the Pallas
     kernel) on a TPU backend for single-configuration planes whose
-    footprint fits ``_VMEM_BUDGET``, else ``"scan"``.  Shared by the traced
-    function and the launch counters, so the two cannot disagree."""
+    footprint fits ``_VMEM_BUDGET`` one way or the other
+    (``_vmem_planes``), else ``"scan"``.  Shared by the traced function
+    and the launch counters, so the two cannot disagree."""
     if (jax.default_backend() != "tpu" or multi
-            or _vmem_bytes(B, W, P, RF, LI, II, n_inv) > _VMEM_BUDGET):
+            or not _vmem_planes(B, W, P, RF, LI, II, n_inv)):
         return "scan"
     return "vmem"
 
@@ -532,15 +555,20 @@ def _mux_maps(P: int, RF: int, LI: int):
     return row, crow, col
 
 
-def _one_hot(port_idx: jnp.ndarray, to_row: np.ndarray, col: np.ndarray,
-             rows: int, cols: int) -> jnp.ndarray:
-    """[II, rows, cols] bf16: 1 where output column ``col[pe, k]`` reads
-    block row ``to_row[port_idx[slot, pe, k]]``."""
+def _mux_rows(port_idx: jnp.ndarray, to_row: np.ndarray, col: np.ndarray,
+              cols: int) -> jnp.ndarray:
+    """[II, cols] int32: the block row output column ``col[pe, k]`` reads,
+    ``to_row[port_idx[slot, pe, k]]``, or -1 where it reads none."""
     II = port_idx.shape[0]
     src = jnp.take(jnp.asarray(to_row), port_idx.astype(jnp.int32)
                    .reshape(II, -1))
-    by_col = jnp.full((II, cols), -1, jnp.int32).at[:, col.reshape(-1)].set(
+    return jnp.full((II, cols), -1, jnp.int32).at[:, col.reshape(-1)].set(
         src)
+
+
+def _one_hot(by_col: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """[II, rows, cols] bf16: 1 where column c of slot s reads block row
+    ``by_col[s, c]`` (``_mux_rows``)."""
     return (by_col[:, None, :] == jnp.arange(rows)[None, :, None]).astype(
         jnp.bfloat16)
 
@@ -581,8 +609,19 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
     i32 = jnp.int32
 
     row, crow, col = _mux_maps(P, RF, LI)
-    oh = _one_hot(c["port_idx"], row, col, KR, NW)
-    ohc = _one_hot(c["port_idx"], crow, col, CW, NW)
+    src = _mux_rows(c["port_idx"], row, col, NW)
+    csrc = _mux_rows(c["port_idx"], crow, col, NW)
+    resident = _vmem_planes(B, W, P, RF, LI, II, n_inv) == "resident"
+    if resident:               # every slot's planes, built once per launch
+        src, csrc = _one_hot(src, KR), _one_hot(csrc, CW)
+    else:                      # each slot's source rows, [II, 1, NW]
+        src, csrc = src[:, None, :], csrc[:, None, :]
+
+    def plane(ref, s, rows):  # slot s's one-hot plane, [rows, NW]
+        if resident:
+            return ref[s]
+        iota = jax.lax.broadcasted_iota(i32, (rows, NW), 0)
+        return jnp.where(iota == ref[s], 1.0, 0.0).astype(jnp.bfloat16)
 
     def lanes(x, width):          # [II, n] -> [II, 1, width], zero-padded
         x = x.astype(i32)
@@ -627,7 +666,7 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
 
             def constants(s, carry):
                 cblk = jnp.broadcast_to(imm_ref[s] + li_row, (8, CW))
-                vc_ref[s] = _mux(cblk, ohc_ref[s], bits)[0:1]
+                vc_ref[s] = _mux(cblk, plane(ohc_ref, s, CW), bits)[0:1]
                 return carry
 
             jax.lax.fori_loop(0, II, constants, 0)
@@ -654,8 +693,8 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
                 # step runs cycle t's LOADs
                 x, fu, ldp, fl, a_prev, b_prev = st
                 s = t % II
-                v = _mux(jnp.concatenate([x, fu], axis=1), oh_ref[s],
-                         bits) + vc_ref[s]                    # [B8, NW]
+                v = _mux(jnp.concatenate([x, fu], axis=1),
+                         plane(oh_ref, s, KR), bits) + vc_ref[s]  # [B8, NW]
                 ops = jnp.where(t < fb_ref[s], fv_ref[s], v[:, XW:])
                 a = ops[:, :PL]
                 b = pltpu.roll(ops, OW - P, 1)[:, :PL]
@@ -699,7 +738,7 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
 
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    limit = _vmem_bytes(B, W, P, RF, LI, II, n_inv) + (16 << 20)
+    limit = _vmem_bytes(B, W, P, RF, LI, II, n_inv, resident) + (16 << 20)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B8, Wp), i32),
@@ -710,7 +749,7 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
         name="morpher_sim_cycles",
-    )(oh, ohc, imm, li, wmask, fb, fv, opc, mem, *smem)
+    )(src, csrc, imm, li, wmask, fb, fv, opc, mem, *smem)
     return out[:B, :W].astype(mem0.dtype)
 
 
@@ -757,17 +796,22 @@ def _launch(sig: simcache.SimSignature, planes: Dict, mem: np.ndarray,
     ``morpher.sim.launch`` span with the launch's counters as attrs:
     scan steps launched (bucketed cycles x invocations), rows (bucketed
     batch) and row-steps, the real rows and row-steps among them, whether
-    which body ran (``_body``: the VMEM kernel or the scan), whether the
-    scan took the pre-tiled streams (never on the kernel), and whether
-    this launch built the executable."""
+    which body ran (``_body``: the VMEM kernel or the scan), the fabric's
+    PEs (``pes``), the VMEM the kernel holds for these shapes
+    (``vmem_bytes``, ``_vmem_bytes`` as ``_vmem_planes`` would hold the
+    planes), whether the scan took the pre-tiled streams (never on the
+    kernel), and whether this launch built the executable."""
     n_inv = int(li_stack.shape[0])
     steps = sig.n_cycles * n_inv
-    body = _body(sig.multi, sig.batch, mem.shape[-1], sig.P, sig.RF,
-                 li_stack.shape[-1], sig.II, n_inv)
+    shapes = (sig.batch, mem.shape[-1], sig.P, sig.RF, li_stack.shape[-1],
+              sig.II, n_inv)
+    body = _body(sig.multi, *shapes)
     with obs.span("morpher.sim.launch", multi=sig.multi, invocations=n_inv,
                   steps=steps, rows=sig.batch, real_rows=real_rows,
                   row_steps=steps * sig.batch,
-                  real_row_steps=real_row_steps, body=body,
+                  real_row_steps=real_row_steps, body=body, pes=sig.P,
+                  vmem_bytes=_vmem_bytes(
+                      *shapes, _vmem_planes(*shapes) != "built"),
                   pretiled=(body == "scan"
                             and _pretiled(planes, sig.II, sig.n_cycles)),
                   built=False) as attrs:

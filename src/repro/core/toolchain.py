@@ -119,8 +119,9 @@ def _compile_worker(payload: str) -> str:
 
 def _map_in_process(spec: KernelSpec, opt: MapperOptions):
     """Map one kernel and generate its configuration in this process."""
-    with obs.span("morpher.map", kernel=spec.name, pool=False):
+    with obs.span("morpher.map", kernel=spec.name, pool=False) as attrs:
         mapping = map_kernel_opts(spec.dfg, spec.arch, spec.layout, opt)
+        attrs.update(ii=mapping.II, mii=mapping.mii)
     with obs.span("morpher.config_gen", kernel=spec.name):
         cfg = generate_config(mapping, spec.layout)
     return mapping, cfg

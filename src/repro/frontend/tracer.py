@@ -35,7 +35,8 @@ legacy Table-I kernels are pinned to in ``tests/test_frontend.py``.
 """
 from __future__ import annotations
 
-from typing import Callable, Iterable, List, Optional, Sequence, Union
+import contextlib
+from typing import Callable, Iterable, Iterator, List, Optional, Sequence, Union
 
 from ..core.dfg import DFG, DFGBuilder, Node, Op, Operand
 from ..core.layout import DataLayout
@@ -345,6 +346,30 @@ class KernelContext:
             vals.append(v)
         vals.append(self.gated_counter(lv[0][1], carry))
         return tuple(reversed(vals))
+
+    def running_sum(self, v: IntOrTraced, name: str = "acc") -> TracedValue:
+        """Register accumulator over the mapped loop: ``acc += v`` each
+        iteration, 0 before the first of every invocation."""
+        vid = self._coerce(v)
+        acc = self._b.add(Operand(0, 0), vid, name=name)
+        self._b.dfg.nodes[acc].operands = (Operand(acc, dist=1, init=0),
+                                           Operand(vid))
+        return TracedValue(self, acc)
+
+    @contextlib.contextmanager
+    def partition(self) -> Iterator[None]:
+        """Trace a body that shares no node with the rest of the kernel:
+        constants and live-ins are cached afresh inside, so the partition is
+        a connected component of its own (one cluster's share of a layer;
+        see ``core.mapper``).  Live-in names must differ between
+        partitions."""
+        b = self._b
+        saved = b._const_cache, b._livein_cache
+        b._const_cache, b._livein_cache = {}, {}
+        try:
+            yield
+        finally:
+            b._const_cache, b._livein_cache = saved
 
     def loop_carried(self, store: TracedValue, load: TracedValue,
                      dist: int = 1) -> None:

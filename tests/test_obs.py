@@ -92,14 +92,17 @@ def test_launch_counters_match_shapes(ck, monkeypatch):
     real = cfg.n_cycles(ck.mapped_iters)
     steps = simcache.bucket_cycles(real) * n_inv
     banks = [generate_test_data(ck.spec, s).init_banks for s in (1, 2, 3)]
-    body = simulator._body(False, 4, cfg.total_words, cfg.P, cfg.RF,
-                           max(1, cfg.LI), cfg.II, n_inv)
+    shapes = (4, cfg.total_words, cfg.P, cfg.RF, max(1, cfg.LI), cfg.II,
+              n_inv)
+    body = simulator._body(False, *shapes)
     simcache.clear()
     tiled = ck.run_batch(banks)
     first = _last("morpher.sim.launch")["attrs"]
     assert first == {"multi": False, "invocations": n_inv, "steps": steps,
                      "rows": 4, "real_rows": 3, "row_steps": steps * 4,
                      "real_row_steps": real * n_inv * 3, "body": body,
+                     "pes": cfg.P,
+                     "vmem_bytes": simulator._vmem_bytes(*shapes),
                      "pretiled": True, "built": True}
     ck.run_batch(banks)
     assert _last("morpher.sim.launch")["attrs"]["built"] is False

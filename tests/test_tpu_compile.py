@@ -106,6 +106,25 @@ def test_simulator_paper_long_launches(one_chip, chip_backend, name,
     assert "tpu_custom_call" in text and "input_output_alias" in text
 
 
+@pytest.mark.parametrize("build,ii_start,planes", [
+    ("build_conv2d", None, "resident"),   # the kws cell's CONV1 at its II
+    ("build_pwconv", 16, "built"),        # 64 PEs past the resident budget
+])
+def test_simulator_kws_layers_64_pes(one_chip, chip_backend, build, ii_start,
+                                     planes):
+    from repro.core.mapper import MapperOptions
+    from repro.core.simulator import _vmem_planes
+    from repro.frontend import layers
+    ck = Toolchain(cache_dir="", options=MapperOptions(ii_start=ii_start)) \
+        .compile(getattr(layers, build)())
+    cfg = ck.cfg
+    assert cfg.P == 64
+    assert _vmem_planes(8, cfg.total_words, cfg.P, cfg.RF, max(1, cfg.LI),
+                        cfg.II, len(ck.invocations)) == planes
+    text = _compile_batch8(ck, one_chip)
+    assert "tpu_custom_call" in text and "input_output_alias" in text
+
+
 def test_simulator_stacked_multi(one_chip, chip_backend, paper_gemm):
     cfg, n_inv = paper_gemm.cfg, len(paper_gemm.invocations)
     rf = simcache.bucket_rf(cfg.RF)
