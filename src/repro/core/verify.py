@@ -117,9 +117,11 @@ def reference_banks_batch(dfg, init_banks, invocations, mapped_iters: int,
     verification.  The heavy lifting runs on the JAX-lowered DFG executor
     (``repro.core.refexec``); ``DFG.reference_execute_batch`` is its
     bit-identical numpy reference (pinned by tests)."""
-    from .refexec import reference_execute_jax
+    from .refexec import oracle_body, reference_execute_jax
     rows = len(next(iter(init_banks.values()))) if init_banks else 0
-    with obs.span("morpher.oracle", rows=rows):
+    with obs.span("morpher.oracle", rows=rows,
+                  body=oracle_body(dfg, init_banks),
+                  steps=len(invocations) * mapped_iters):
         return reference_execute_jax(dfg, mapped_iters, init_banks,
                                      invocations, bits=bits)
 

@@ -168,7 +168,9 @@ def test_verify_batch_spans_share_one_root(ck):
     top, under = _tree("morpher.verify_batch")
     assert top["attrs"] == {"kernel": ck.name, "seeds": 3}
     assert {r["name"] for r in under} == VERIFY_SPANS
-    assert _last("morpher.oracle")["attrs"] == {"rows": 3}
+    assert _last("morpher.oracle")["attrs"] == {
+        "rows": 3, "body": "scan",
+        "steps": len(ck.invocations) * ck.mapped_iters}
     for r in under:
         if r is not top:
             assert top["t0_ns"] <= r["t0_ns"] <= r["t1_ns"] <= top["t1_ns"]
@@ -242,7 +244,7 @@ def test_device_programs_have_stable_names(ck):
     li_names = tuple(sorted({n.livein for n in spec.dfg.nodes.values()
                              if n.op == Op.LIVEIN}))
     fn = _lowered(spec.dfg, n_iters=spec.mapped_iters,
-                  bits=spec.arch.datapath_bits, B=2, banks=banks,
+                  bits=spec.arch.datapath_bits, banks=banks,
                   li_names=li_names)
     stride = sum(w for _, w in banks) + 1
     assert _module(fn.lower(

@@ -145,18 +145,24 @@ def test_simulator_stacked_multi(one_chip, chip_backend, paper_gemm):
 
 
 def test_refexec_oracle_table1_conv(one_chip, chip_backend):
-    spec = table1_kernels()["CONV-U-C-1"]
-    banks = tuple(sorted((f"bank{bid}", w) for bid, w in
-                         spec.layout.bank_image_size().items()))
-    li_names = tuple(sorted({n.livein for n in spec.dfg.nodes.values()
-                             if n.op == Op.LIVEIN}))
-    fn = _lowered(spec.dfg, n_iters=spec.mapped_iters,
-                  bits=spec.arch.datapath_bits, B=8, banks=banks,
-                  li_names=li_names)
-    stride = sum(w for _, w in banks) + 1
-    mem0 = np.zeros((8 * stride,), np.int32)
-    li = np.zeros((len(spec.invocations), len(li_names)), np.int32)
-    fn.lower(*_shapes((mem0, li), one_chip)).compile()
+    """The oracle's VMEM kernel at B=8 for Table-I CONV-U-C-1 at the
+    paper's dims and for the KWS DW layer, the largest DFG of both cells
+    (324 nodes, 76 loads)."""
+    from repro.frontend import layers
+    for spec in (table1_kernels()["CONV-U-C-1"],
+                 layers.build_dwconv_layer()):
+        banks = tuple(sorted((f"bank{bid}", w) for bid, w in
+                             spec.layout.bank_image_size().items()))
+        li_names = tuple(sorted({n.livein for n in spec.dfg.nodes.values()
+                                 if n.op == Op.LIVEIN}))
+        fn = _lowered(spec.dfg, n_iters=spec.mapped_iters,
+                      bits=spec.arch.datapath_bits, banks=banks,
+                      li_names=li_names)
+        stride = sum(w for _, w in banks) + 1
+        mem0 = np.zeros((8 * stride,), np.int32)
+        li = np.zeros((len(spec.invocations), len(li_names)), np.int32)
+        text = fn.lower(*_shapes((mem0, li), one_chip)).compile().as_text()
+        assert "tpu_custom_call" in text, spec.name
 
 
 def test_rwkv6_1_6b_decode_batch4(one_chip):
