@@ -5,11 +5,8 @@
                      exploration use-case of the ADL)
   kernel_micro    -- Pallas kernels: us/call in interpret mode (correctness
                      harness timing; real perf comes from the roofline)
-  sim_throughput  -- JAX simulator cycles/s (the Verilator-replacement claim)
   toolchain_cache -- cold vs warm Toolchain.compile over the Table-I kernel
                      set (the content-addressed artifact cache)
-  verify_batched  -- per-seed sequential verify vs the batched verification
-                     engine (vmapped multi-seed simulation) at batch=8
   dse_sweep       -- tiny design-space sweep (repro.dse): 4 architecture
                      variants x the ten-kernel library; rows are modeled
                      suite latency per variant (deterministic), so the
@@ -25,7 +22,7 @@ machine-readable rows; ``main`` writes one ``BENCH_<name>.json`` artifact
 per benchmark (schema: ``{"bench", "schema", "git_sha", "rows": [{"name",
 "us", "derived": {...}}]}``) so the perf trajectory is tracked PR-over-PR.
 
-CLI:  python -m benchmarks.run [--only sim_throughput,toolchain_cache]
+CLI:  python -m benchmarks.run [--only toolchain_cache,frontend_trace]
                                [--out DIR]
       python -m benchmarks.run --check-regression before.json after.json
                                [--tol 0.15]
@@ -142,27 +139,6 @@ def bench_kernel_micro() -> List[Dict]:
     return rows
 
 
-def bench_sim_throughput() -> List[Dict]:
-    from repro.core.kernels_lib import build_gemm
-    from repro.core.toolchain import Toolchain
-    from repro.core.verify import generate_test_data
-
-    spec = build_gemm(TI=6, TK=8, TJ=6, unroll=1)
-    ck = Toolchain(cache_dir="").compile(spec)
-    data = generate_test_data(spec)
-    n_cycles = ck.cfg.n_cycles(spec.mapped_iters) * len(spec.invocations)
-    ck.run(data.init_banks)
-    dt = float("inf")                 # best of 3: shields against noise
-    for _ in range(3):
-        t0 = time.time()
-        ck.run(data.init_banks)
-        dt = min(dt, time.time() - t0)
-    rows = [_row("simulator_gemm", dt * 1e6, cycles=n_cycles,
-                 cycles_per_s=round(n_cycles / dt))]
-    _print_rows(rows)
-    return rows
-
-
 def bench_toolchain_cache() -> List[Dict]:
     """Cold vs warm compile of the Table-I kernel set through the content-
     addressed artifact cache (small dims, identical DFG structure)."""
@@ -192,49 +168,6 @@ def bench_toolchain_cache() -> List[Dict]:
         return rows
     finally:
         shutil.rmtree(cache, ignore_errors=True)
-
-
-def bench_verify_batched() -> List[Dict]:
-    """Aggregate verification throughput over the Table-I (small dims) +
-    DSL kernel set: per-seed sequential ``verify`` vs one ``verify_batch``
-    per kernel at batch=8 (the batched engine: vectorized test-data
-    generation, batched numpy DFG oracle, vmapped simulator through the
-    process-wide executable cache).  Target: >= 3x."""
-    from repro.core import simcache
-    from repro.core.kernels_lib import table1_kernels
-    from repro.core.toolchain import Toolchain
-    from repro.frontend.library import dsl_kernels
-
-    seeds = list(range(8))
-    specs = {**table1_kernels(small=True), **dsl_kernels()}
-    cks = Toolchain(cache_dir="").compile_many(list(specs.values()))
-    # warm both paths once so XLA traces (amortized by the persistent
-    # executable cache in any real verification fleet) are off the clock
-    for ck in cks:
-        ck.verify(seed=seeds[0])
-        ck.verify_batch(seeds)
-    trace_stats = simcache.stats()
-
-    t0 = time.time()
-    for ck in cks:
-        for s in seeds:
-            ck.verify(seed=s)
-    seq = time.time() - t0
-    t0 = time.time()
-    for ck in cks:
-        ck.verify_batch(seeds)
-    bat = time.time() - t0
-
-    n = len(cks) * len(seeds)
-    rows = [_row("verify_batched", bat * 1e6,
-                 seq_us=round(seq * 1e6), kernels=len(cks),
-                 batch=len(seeds), verifies=n,
-                 seq_verifies_per_s=round(n / seq, 1),
-                 batch_verifies_per_s=round(n / bat, 1),
-                 speedup=round(seq / bat, 2),
-                 **_simcache_derived(trace_stats))]
-    _print_rows(rows)
-    return rows
 
 
 def bench_frontend_trace() -> List[Dict]:
@@ -560,11 +493,8 @@ BENCHES = {
                      bench_mapper_sweep),
     "kernel_micro": ("Pallas kernel micro (interpret mode)",
                      bench_kernel_micro),
-    "sim_throughput": ("simulator throughput", bench_sim_throughput),
     "toolchain_cache": ("toolchain artifact cache (cold vs warm)",
                         bench_toolchain_cache),
-    "verify_batched": ("batched vs sequential verification throughput",
-                       bench_verify_batched),
     "dse_sweep": ("tiny design-space sweep (repro.dse, modeled latency)",
                   bench_dse_sweep),
     "dse_search": ("cross-architecture stacked simulation throughput "

@@ -50,7 +50,7 @@ _FLOW = {
     "simulate_batch": ".simulator",
     "generate_test_data": ".verify",
     "generate_test_data_batch": ".verify",
-    "check_dfg_semantics": ".verify",
+    "check_dfg_semantics_batch": ".verify",
     "verify_mapping": ".verify",      # deprecated shim
     # cost model
     "kernel_cost": ".costmodel",

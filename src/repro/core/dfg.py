@@ -15,9 +15,11 @@ Node ops (all execute on a CGRA PE functional unit):
   LOAD    -- read a word from a memory bank: operands (addr,)     (lat 2)
   STORE   -- write a word to a memory bank: operands (addr, val)  (lat 1)
 
-A pure sequential ``reference_execute`` gives the oracle semantics used by
-the verification flow (paper section IV-C): the modulo-scheduled, pipelined
-CGRA simulation must produce the same final memory state.
+A pure sequential ``reference_execute`` states the oracle semantics of the
+verification flow (paper section IV-C): the modulo-scheduled, pipelined
+CGRA simulation must produce the same final memory state.  Verification
+runs them on the JAX DFG oracle (``repro.core.refexec``); tests pin that
+oracle to ``reference_execute``.
 """
 from __future__ import annotations
 
@@ -260,7 +262,13 @@ class DFG:
     def reference_execute(self, n_iters: int, arrays: Dict[str, List[int]],
                           liveins: Dict[str, int],
                           bits: int = DATAPATH_BITS) -> Dict[str, List[int]]:
-        """Sequential (non-pipelined) execution: the verification oracle.
+        """Sequential (non-pipelined) execution of one invocation: the
+        plain reference of the loop's semantics.
+
+        Production does not call it: verification runs the JAX DFG oracle
+        (``repro.core.refexec``).  Tests fold this over the invocations,
+        one image at a time, and pin both of the oracle's bodies to it
+        word for word.
 
         arrays: name -> flat word list (mutated copy returned).
         liveins: live-in scalar values for this invocation.
@@ -336,111 +344,6 @@ class DFG:
                 h.append(cur[vid])
                 if len(h) > maxdist:
                     h.pop(0)
-        return mem
-
-    def reference_execute_batch(self, n_iters: int, arrays, invocations,
-                                bits: int = DATAPATH_BITS):
-        """``reference_execute`` vectorized over a leading batch axis and
-        folded over all invocations in one call.
-
-        arrays: name -> int array of shape [batch, words] (one row per
-        test vector); invocations: the host outer-loop livein dicts; a
-        fresh dict of final images is returned.  Per row the result is
-        bit-identical to folding the scalar oracle over the invocations:
-        every node value becomes a [batch] int64 vector, wrapped to the
-        datapath width after each op exactly as the scalar path wraps its
-        Python ints (operands are always in 16-bit range, so int64
-        intermediates never overflow).  The node program (topological
-        order, operand bindings) is compiled once for the whole sweep,
-        which together with the batch vectorization keeps the numpy
-        oracle off the critical path when the batched verification engine
-        checks many seeds at once.
-        """
-        import numpy as np
-        mem = {k: np.array(v, dtype=np.int64) for k, v in arrays.items()}
-        B = next(iter(mem.values())).shape[0] if mem else 1
-        rows = np.arange(B)
-        half, full = 1 << (bits - 1), 1 << bits
-
-        def awrap(x):
-            return ((x + half) & (full - 1)) - half
-
-        order = self.topo_order()
-        prog = [(vid, self.nodes[vid]) for vid in order]
-        maxdist = max([o.dist for _s, _d, _sl, o in self.data_edges()] + [0])
-
-        def read(opnd: Operand, cur, hist):
-            if opnd.dist == 0:
-                return cur[opnd.src]
-            h = hist[opnd.src]
-            if len(h) < opnd.dist:
-                return np.full(B, wrap(opnd.init, bits), dtype=np.int64)
-            return h[-opnd.dist]
-
-        for inv in invocations:
-            hist: Dict[int, List] = {i: [] for i in self.nodes}
-            for _it in range(n_iters):
-                cur: Dict[int, "np.ndarray"] = {}
-                for vid, n in prog:
-                    if n.op == Op.CONST:
-                        cur[vid] = np.full(B, wrap(n.imm, bits),
-                                           dtype=np.int64)
-                    elif n.op == Op.LIVEIN:
-                        cur[vid] = np.full(B, wrap(inv[n.livein], bits),
-                                           dtype=np.int64)
-                    elif n.op == Op.LOAD:
-                        addr = read(n.operands[0], cur, hist)
-                        buf = mem[n.array]
-                        ok = (addr >= 0) & (addr < buf.shape[1])
-                        cur[vid] = np.where(
-                            ok, buf[rows, np.clip(addr, 0,
-                                                  buf.shape[1] - 1)], 0)
-                    elif n.op == Op.STORE:
-                        addr = read(n.operands[0], cur, hist)
-                        val = read(n.operands[1], cur, hist)
-                        buf = mem[n.array]
-                        ok = (addr >= 0) & (addr < buf.shape[1])
-                        buf[rows[ok], addr[ok]] = val[ok]
-                        cur[vid] = np.zeros(B, dtype=np.int64)
-                    else:
-                        a = read(n.operands[0], cur, hist)
-                        b = read(n.operands[1], cur, hist) \
-                            if len(n.operands) > 1 \
-                            else np.zeros(B, dtype=np.int64)
-                        if n.op == Op.ADD:
-                            r = a + b
-                        elif n.op == Op.SUB:
-                            r = a - b
-                        elif n.op == Op.MUL:
-                            r = a * b
-                        elif n.op == Op.SHL:
-                            r = a << (b & (bits - 1))
-                        elif n.op == Op.SHR:
-                            r = a >> (b & (bits - 1))
-                        elif n.op == Op.AND:
-                            r = a & b
-                        elif n.op == Op.OR:
-                            r = a | b
-                        elif n.op == Op.XOR:
-                            r = a ^ b
-                        elif n.op == Op.CMPGE:
-                            r = (a >= b).astype(np.int64)
-                        elif n.op == Op.CMPEQ:
-                            r = (a == b).astype(np.int64)
-                        elif n.op == Op.CMPLT:
-                            r = (a < b).astype(np.int64)
-                        elif n.op == Op.SELECT:
-                            c = read(n.operands[2], cur, hist)
-                            r = np.where(a != 0, b, c)
-                        else:
-                            raise NotImplementedError(n.op)
-                        cur[vid] = awrap(r)
-                if maxdist:
-                    for vid in order:
-                        h = hist[vid]
-                        h.append(cur[vid])
-                        if len(h) > maxdist:
-                            h.pop(0)
         return mem
 
 

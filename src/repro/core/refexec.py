@@ -1,10 +1,11 @@
 """Batched DFG reference execution lowered to JAX (the fast oracle).
 
-``DFG.reference_execute`` is the verification oracle of the paper's IV-C
-flow: sequential, non-pipelined dataflow execution of the mapped loop.
-The pure-Python interpreter is exactly right for one seed, but a batched
-verification sweep runs it over every seed of every invocation, where the
-per-node Python dispatch dominates the whole verify pipeline.
+This is the DFG oracle of the paper's IV-C flow, the only one production
+runs: sequential, non-pipelined dataflow execution of the mapped loop over
+every seed and invocation of a verification in one program.  The plain
+reference of the same semantics is ``DFG.reference_execute``, a
+pure-Python interpreter of one image and one invocation that only tests
+call.
 
 This module compiles a DFG into one jitted program (its XLA module is
 ``jit_morpher_refexec``) with one of two bodies, chosen by ``_body``:
@@ -26,9 +27,9 @@ table of both bodies): values wrap to the datapath width after every
 node, out-of-range loads read 0, out-of-range stores drop, and
 loop-carried operands read their ``init`` value for the first ``dist``
 iterations.  ``tests/test_batched_verify.py`` pins the scan word-for-word
-against both the scalar interpreter and the numpy batch interpreter for
-every library kernel; ``tests/test_refexec_vmem.py`` pins the kernel
-against the scan.
+against the scalar interpreter folded over the invocations;
+``tests/test_refexec_vmem.py`` pins the kernel against the scan and both
+against that same reference.
 
 Compiled executables are cached on the DFG instance keyed by the
 execution shape, so re-verifying the same kernel across seed batches

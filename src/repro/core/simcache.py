@@ -1,8 +1,9 @@
 """Process-wide shape-bucketed cache of compiled simulator executables.
 
-The batched verification engine (``simulator.simulate_batch``) compiles one
-XLA executable per *shape signature* — ``(II, P, RF, bits, n_iters,
-n_cycles, batch)`` — not per call.  Verifying the six Table-I kernels plus
+Every simulator launch (``simulate``, ``simulate_batch``,
+``simulate_multi``, all through ``simulator._launch``) takes one XLA
+executable per *shape signature* — ``(II, P, RF, bits, n_iters, n_cycles,
+batch)`` — not per call.  Verifying the six Table-I kernels plus
 the four DSL kernels across N seeds therefore triggers a handful of traces
 instead of one per ``verify`` call, and repeated verification sweeps (CI,
 architecture exploration) reuse the executables for the lifetime of the
@@ -15,7 +16,8 @@ Three bucketing knobs cap retraces from near-miss shapes:
   * ``bucket_cycles`` rounds the cycle count up, keeping 4 significant
     bits (<= 12.5% padded cycles) — cycles past the schedule are dead by
     construction: every STORE is gated by the control module's
-    iteration-validity window, so final memory is untouched;
+    iteration-validity window, so final memory is untouched
+    (``simulate`` alone keeps the exact count);
   * ``bucket_rf`` (multi-architecture stacking only) rounds the
     register-file width up so fabrics differing only in RF provisioning
     share one executable — padded registers are dead lanes (write ports

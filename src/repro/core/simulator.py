@@ -31,15 +31,18 @@ cycle word for word:
 This is the component that makes verification fast enough to run in CI
 for every mapped kernel.
 
-Both entry points run one shared traced body with a leading batch axis of
-memory images (``simulate`` is the batch-of-one case):
+Every entry point launches through ``_launch``: one executable from the
+process-wide cache (``repro.core.simcache``), under the
+``morpher.sim.launch`` span and its counters.  The traced body has a
+leading batch axis of memory images:
 
-  * ``simulate`` — one memory image (the historical per-seed path);
+  * ``simulate`` — one memory image for exactly the schedule's cycles
+    (``CompiledKernel.run``, the mutation probe, ISA cross-validation);
   * ``simulate_batch`` — many seeds / test vectors of the same compiled
-    kernel in a single XLA launch, with the batched image buffer donated.
-    Executables come from a process-wide shape-bucketed cache
-    (``repro.core.simcache``), so a verification fleet across many kernels
-    and seeds triggers a handful of traces, not one per call;
+    kernel in a single launch, with the batched image buffer donated and
+    the batch and cycle count bucketed, so a verification fleet across
+    many kernels and seeds triggers a handful of traces, not one per call
+    (``verify_batch``, and so every verify);
   * ``simulate_multi`` — many *configurations* sharing a shape bucket
     (``stack_signature``) in a single XLA launch: the config planes gain a
     leading batch-row axis and ride alongside the memory images, so one
@@ -65,7 +68,6 @@ before entering the traced body: the pre-tiled per-cycle streams shrink
 """
 from __future__ import annotations
 
-import functools
 from collections import OrderedDict
 from typing import Dict, List, Sequence, Tuple
 
@@ -783,12 +785,6 @@ def _build_batched(sig: simcache.SimSignature):
                    donate_argnums=donate)
 
 
-# executables of the per-seed ``simulate`` path, by exact signature (batch
-# 1, cycles unbucketed); kept apart from ``simcache``, which counts the
-# batched launches
-_build_single = functools.lru_cache(maxsize=None)(_build_batched)
-
-
 def _launch(sig: simcache.SimSignature, planes: Dict, mem: np.ndarray,
             li_stack: np.ndarray, real_rows: int,
             real_row_steps: int) -> np.ndarray:
@@ -840,24 +836,34 @@ def _mem_to_banks(cfg: SimConfig, mem: np.ndarray,
 
 
 def simulate(cfg: SimConfig, banks: Dict[str, np.ndarray],
-             invocations, n_iters: int,
-             liveins_builder=None) -> Dict[str, np.ndarray]:
+             invocations, n_iters: int) -> Dict[str, np.ndarray]:
     """Run the mapped kernel for every invocation and return final banks.
 
     banks: {"bank<i>": int array} initial memory images.
     invocations: list of {livein name: value} dicts (host outer loops).
-    """
-    mem = _banks_to_mem(cfg, banks)
-    if not len(invocations):
-        # nothing to run: the final image is the initial image
-        return _mem_to_banks(cfg, mem, banks)
 
-    li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
-    fn = _build_single(simcache.SimSignature(
-        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
-        n_cycles=cfg.n_cycles(n_iters), batch=1))
-    out = fn(_as_jnp(cfg), jnp.asarray(mem[None, :]), jnp.asarray(li_stack))
-    return _mem_to_banks(cfg, np.asarray(out)[0], banks)
+    One image through ``_launch`` and ``simcache``, like every launch, but
+    for exactly ``cfg.n_cycles(n_iters)`` cycles rather than the bucketed
+    count ``simulate_batch`` pads to.  Callers that hold a config to its
+    real schedule length rely on that: ``check.mutate._probe_dead`` calls
+    a mutant dead only if it is execution-equivalent over the real cycles,
+    and a mutant whose stores move past the last real cycle would commit
+    them in a bucketed run's padded cycles.
+    """
+    with obs.span("morpher.sim.planes"):
+        mem = _banks_to_mem(cfg, banks)
+        if not len(invocations):
+            # nothing to run: the final image is the initial image
+            return _mem_to_banks(cfg, mem, banks)
+        li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
+        n_cycles = cfg.n_cycles(n_iters)
+        sig = simcache.SimSignature(
+            II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
+            n_cycles=n_cycles, batch=1)
+        planes = _as_jnp(cfg)
+    out = _launch(sig, planes, mem[None, :], li_stack, real_rows=1,
+                  real_row_steps=n_cycles * len(invocations))
+    return _mem_to_banks(cfg, out[0], banks)
 
 
 def simulate_batch(cfg: SimConfig, banks_batch: List[Dict[str, np.ndarray]],

@@ -16,8 +16,10 @@ This module is that pipeline's front door:
 data layout, the :class:`Mapping`, and the generated :class:`SimConfig` —
 and is fully JSON-serializable (CGRA4ML-style artifact-oriented HW/SW
 handoff).  A deserialized artifact carries no Python closures, so its
-``verify`` falls back to the DFG's sequential reference execution as the
-oracle; both paths are bit-exact comparisons of final memory images.
+``verify`` takes the JAX DFG oracle (``repro.core.refexec``) on
+deterministic random bank images instead of the golden model; both are
+bit-exact comparisons of final memory images.  ``verify_batch`` is the one
+verification engine: ``verify(seed)`` is its batch of one.
 
 Compiles are memoized through a content-addressed on-disk cache keyed by a
 stable SHA-256 of (DFG canonical form, arch ADL JSON, mapper options, data
@@ -190,7 +192,8 @@ class CompiledKernel:
 
     # ------------------------------------------------------------ execution
     def run(self, init_banks: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-        """Cycle-accurately simulate all invocations; returns final banks."""
+        """Cycle-accurately simulate all invocations for exactly the
+        schedule's cycles; returns final banks."""
         from .simulator import simulate
         return simulate(self.cfg, init_banks, self.invocations,
                         self.mapped_iters)
@@ -212,51 +215,22 @@ class CompiledKernel:
 
     def verify(self, seed: int = 0, check_dfg: bool = True
                ) -> "CompiledKernel":
-        """Paper IV-C functional verification; raises AssertionError on any
-        final-memory mismatch, returns self on success.
-
-        With the builder spec attached (fresh compiles), the oracle is the
-        kernel's golden numpy model on spec-generated test data.  Without it
-        (artifacts reloaded from JSON), the oracle is sequential DFG
-        reference execution on deterministic random bank images — the same
-        bit-exact contract, self-contained in the artifact.
-        """
-        _check_gate([self])
-        if self.spec is not None:
-            from .verify import check_dfg_semantics, generate_test_data
-            data = generate_test_data(self.spec, seed)
-            if check_dfg:
-                check_dfg_semantics(self.spec, data)
-            init, expected = data.init_banks, data.expected_banks
-        else:
-            from .verify import reference_banks
-            init = self.random_banks(seed)
-            banks = reference_banks(self.dfg, init, self.invocations,
-                                    self.mapped_iters,
-                                    self.arch.datapath_bits)
-            expected = {k: np.asarray(v) for k, v in banks.items()}
-        final = self.run(init)
-        for bank, exp in expected.items():
-            got = np.asarray(final[bank])
-            exp = np.asarray(exp)
-            if not np.array_equal(got, exp):
-                bad = np.nonzero(got != exp)[0][:8]
-                raise AssertionError(
-                    f"{self.name} (II={self.II}): simulation mismatch in "
-                    f"{bank} at words {bad.tolist()}: got {got[bad]}, "
-                    f"want {exp[bad]}")
-        _xval_gate([self], (seed,))
-        return self
+        """Paper IV-C functional verification of one seed: ``verify_batch``
+        on a batch of one, with its oracle, comparison and spans.  Raises
+        AssertionError naming the seed, bank and words on any mismatch;
+        returns self on success."""
+        return self.verify_batch((seed,), check_dfg)
 
     def verify_batch(self, seeds: Sequence[int] = (0,),
                      check_dfg: bool = True) -> "CompiledKernel":
         """Paper IV-C verification over many seeds in one batched pass.
 
+        The one verification engine (``verify`` is its batch of one).
         All test vectors are generated up front, the DFG oracle runs once
         vectorized over the seed axis, and the cycle-accurate simulation is
-        a single vmapped XLA launch through the process-wide executable
-        cache — with results bit-identical to per-seed ``verify`` (pinned
-        by the golden-equivalence tests).  Live-out banks (the ones STORE
+        a single batched launch through the process-wide executable cache,
+        each row bit-identical to ``run`` on its image (pinned by the
+        golden-equivalence tests).  Live-out banks (the ones STORE
         nodes target) are compared word-for-word against the oracle;
         every other bank is pinned to its initial image, so a miscompiled
         store straying into an input-only bank still fails.  Raises
@@ -342,8 +316,9 @@ def _batch_oracle(ck: CompiledKernel, seeds: Sequence[int],
     batch — the ``verify_batch`` oracle, shared verbatim by the stacked
     multi-architecture path so both report identical results.  With the
     builder spec attached the oracle is the golden numpy model on
-    spec-generated data; reloaded artifacts fall back to sequential DFG
-    reference execution on deterministic random bank images."""
+    spec-generated data; reloaded artifacts fall back to the JAX DFG
+    oracle (``reference_banks_batch``) on deterministic random bank
+    images."""
     if ck.spec is not None:
         from .verify import (check_dfg_semantics_batch,
                              generate_test_data_batch)

@@ -8,16 +8,17 @@ against the expected results.  The same three-step contract here:
   1. *test-data generation*: initialize bank images, record the live-in
      values of every host invocation, and compute expected live-outs with
      the kernel's golden (numpy) model;
-  2. additionally cross-check the DFG itself by sequential dataflow
-     execution (`DFG.reference_execute`) — this separates "the DFG is the
-     right program" from "the mapping executes the DFG correctly";
+  2. additionally cross-check the DFG itself by dataflow execution on the
+     JAX DFG oracle (``repro.core.refexec``, every seed in one pass) —
+     this separates "the DFG is the right program" from "the mapping
+     executes the DFG correctly";
   3. simulate the mapped configuration cycle-by-cycle and compare the
      final memory images word-for-word.
 
-The canonical entry point is ``Toolchain.compile(spec).verify(seed)``
-(`repro.core.toolchain`); this module provides the test-data generator and
-the DFG-semantics cross-check it uses, plus the deprecated
-``verify_mapping`` shim.
+The one engine is ``CompiledKernel.verify_batch`` (`repro.core.toolchain`),
+and ``verify(seed)`` is its batch of one; this module provides the
+test-data generator and the DFG oracle and cross-check it uses, plus the
+deprecated ``verify_mapping`` shim.
 """
 from __future__ import annotations
 
@@ -97,26 +98,14 @@ def generate_test_data_batch(spec: KernelSpec,
                                      for d in datas]) for k in names})
 
 
-def reference_banks(dfg, init_banks, invocations, mapped_iters: int,
-                    bits: int) -> Dict[str, list]:
-    """Fold sequential DFG reference execution over all invocations — the
-    closure-free oracle shared by the DFG cross-check and deserialized-
-    artifact verification."""
-    banks = {k: [int(x) for x in v] for k, v in init_banks.items()}
-    for inv in invocations:
-        banks = dfg.reference_execute(mapped_iters, banks, inv, bits=bits)
-    return banks
-
-
 def reference_banks_batch(dfg, init_banks, invocations, mapped_iters: int,
                           bits: int) -> Dict[str, np.ndarray]:
-    """``reference_banks`` vectorized over the leading seed axis of
-    ``init_banks`` ([batch, words] per bank) — one oracle pass for the
-    whole batch and invocation sweep instead of one per (seed,
-    invocation), so the oracle does not become the bottleneck of batched
-    verification.  The heavy lifting runs on the JAX-lowered DFG executor
-    (``repro.core.refexec``); ``DFG.reference_execute_batch`` is its
-    bit-identical numpy reference (pinned by tests)."""
+    """The DFG oracle: final bank images after every invocation of the
+    loop, for each row of ``init_banks`` ([batch, words] per bank) — one
+    pass of the JAX-lowered DFG executor (``repro.core.refexec``) for the
+    whole batch and invocation sweep.  Tests pin it word for word to
+    ``DFG.reference_execute`` folded over the invocations, row by row;
+    production calls only this."""
     from .refexec import oracle_body, reference_execute_jax
     rows = len(next(iter(init_banks.values()))) if init_banks else 0
     with obs.span("morpher.oracle", rows=rows,
@@ -124,19 +113,6 @@ def reference_banks_batch(dfg, init_banks, invocations, mapped_iters: int,
                   steps=len(invocations) * mapped_iters):
         return reference_execute_jax(dfg, mapped_iters, init_banks,
                                      invocations, bits=bits)
-
-
-def check_dfg_semantics(spec: KernelSpec, data: TestData) -> None:
-    """Step 2: sequential DFG execution must match the golden model."""
-    banks = reference_banks(spec.dfg, data.init_banks, spec.invocations,
-                            spec.mapped_iters, spec.arch.datapath_bits)
-    for name, exp in data.expected_banks.items():
-        got = np.asarray(banks[name])
-        if not np.array_equal(got, exp):
-            bad = np.nonzero(got != np.asarray(exp))[0][:8]
-            raise AssertionError(
-                f"{spec.name}: DFG reference mismatch in {name} at words "
-                f"{bad.tolist()}: got {got[bad]}, want {np.asarray(exp)[bad]}")
 
 
 def check_dfg_semantics_batch(spec: KernelSpec, data: TestDataBatch) -> None:

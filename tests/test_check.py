@@ -92,6 +92,29 @@ def test_corpus_is_seeded_and_reproducible(compiled_small):
         [o.to_json_dict() for o in b.outcomes]
 
 
+def test_probe_dead_simulates_exact_cycles(compiled_small):
+    """The mutation probe judges a config over its real schedule length:
+    each of its launches is one image for exactly the schedule's cycles,
+    never the bucketed count whose padded cycles could commit a store
+    moved past the last real cycle."""
+    from repro.check.mutate import _probe_dead
+    from repro.core import obs, simcache
+    ck = compiled_small["GEMM"]
+    real = ck.cfg.n_cycles(ck.mapped_iters)
+    assert simcache.bucket_cycles(real) > real
+    with obs.span("morpher.t.probe"):
+        assert _probe_dead(ck, "config", ck.cfg)
+        cfg, _desc = mutate_one(ck, "store_window", seed=0, index=0)
+        assert not _probe_dead(ck, "config", cfg)
+    top = obs.spans()[-1]                 # the probe span closes last
+    launches = [r["attrs"] for r in obs.spans()
+                if r["name"] == "morpher.sim.launch"
+                and r["root"] == top["id"]]
+    assert len(launches) >= 2
+    for a in launches:
+        assert (a["rows"], a["steps"]) == (1, real * len(ck.invocations))
+
+
 # --------------------------------------------------------------- the report
 def test_report_json_byte_deterministic(compiled_small):
     def build():

@@ -1,11 +1,12 @@
-"""DFG IR + kernel-library semantics: the sequential dataflow oracle must
-reproduce the numpy golden model for every Table-I kernel variant."""
+"""DFG IR + kernel-library semantics: the DFG oracle must reproduce the
+numpy golden model for every Table-I kernel variant."""
 import numpy as np
 import pytest
 
 from repro.core.dfg import DFG, DFGBuilder, Op, Operand, wrap
 from repro.core.kernels_lib import build_conv, build_gemm, table1_kernels
-from repro.core.verify import check_dfg_semantics, generate_test_data
+from repro.core.verify import (check_dfg_semantics_batch,
+                               generate_test_data_batch)
 
 
 def test_wrap16():
@@ -42,8 +43,8 @@ def test_carried_init_semantics():
                                   "CONV", "CONV-U-C-1", "CONV-U-C-2"])
 def test_kernel_dfg_matches_golden(name):
     spec = table1_kernels(small=True)[name]
-    data = generate_test_data(spec, seed=3)
-    check_dfg_semantics(spec, data)   # raises on mismatch
+    data = generate_test_data_batch(spec, [3])
+    check_dfg_semantics_batch(spec, data)   # raises on mismatch
 
 
 def test_node_counts_paper_ballpark():

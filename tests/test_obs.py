@@ -162,6 +162,30 @@ def test_multi_launch_counters(ck):
     assert attrs["real_row_steps"] == real * n_inv * 5
 
 
+def test_run_launches_through_simcache(ck):
+    """The per-seed ``run`` is one exact-cycle launch through ``simcache``
+    and the launch span, and ``verify`` is ``verify_batch`` of one."""
+    n_inv = len(ck.invocations)
+    init = generate_test_data(ck.spec, 0).init_banks
+    simcache.clear()
+    ck.run(init)
+    attrs = _last("morpher.sim.launch")["attrs"]
+    steps = ck.cfg.n_cycles(ck.mapped_iters) * n_inv
+    assert (attrs["rows"], attrs["real_rows"]) == (1, 1)
+    assert attrs["steps"] == attrs["real_row_steps"] == steps
+    assert attrs["built"] is True
+    assert simcache.stats() == {"entries": 1, "hits": 0, "misses": 1}
+    ck.run(init)
+    assert _last("morpher.sim.launch")["attrs"]["built"] is False
+    assert simcache.stats() == {"entries": 1, "hits": 1, "misses": 1}
+    last = obs.spans()[-1]["id"]
+    ck.verify(seed=3)
+    tops = [r for r in obs.spans()
+            if r["name"] == "morpher.verify_batch" and r["id"] > last]
+    assert [r["attrs"] for r in tops] == [{"kernel": ck.name, "seeds": 1}]
+    simcache.clear()
+
+
 # -------------------------------------------------------- span trees
 def test_verify_batch_spans_share_one_root(ck):
     ck.verify_batch([4, 5, 6])
@@ -187,7 +211,15 @@ def test_gates_and_stacked_verify_spans(ck, monkeypatch):
     top, under = _tree("morpher.verify_stacked")
     assert top["attrs"] == {"kernels": 2, "seeds": 2}
     launches = [r for r in under if r["name"] == "morpher.sim.launch"]
-    assert [r["attrs"]["multi"] for r in launches] == [True]
+    assert [r["attrs"]["multi"] for r in launches
+            if r["parent"] == top["id"]] == [True]
+    # the rest are the cross-validation's exact-cycle runs, one per
+    # (kernel, seed), each a batch of one under the xval span
+    xval = [r for r in under if r["name"] == "morpher.xval"]
+    rest = [r for r in launches if r["parent"] != top["id"]]
+    assert len(xval) == 1 and len(rest) == 4
+    assert all(r["parent"] == xval[0]["id"] and r["attrs"]["rows"] == 1
+               for r in rest)
 
 
 def test_compile_many_spans():
@@ -223,8 +255,6 @@ def test_device_programs_have_stable_names(ck):
     li = jnp.zeros((n_inv, cfg.P, max(1, cfg.LI)), jnp.int32)
     planes = simulator._as_jnp(cfg)
     assert _module(simulator._build_batched(sig).lower(planes, mem, li)) \
-        == "jit_morpher_sim"
-    assert _module(simulator._build_single(sig).lower(planes, mem, li)) \
         == "jit_morpher_sim"
 
     rf = simcache.bucket_rf(cfg.RF)

@@ -1,7 +1,8 @@
 """The DFG oracle's VMEM-resident Pallas body (``refexec._vmem_body``, the
 body a TPU backend runs) against its scan body (``refexec._scan_body``)
-and the numpy batch interpreter (``DFG.reference_execute_batch``), word
-for word, in the Pallas interpreter on the CPU.
+and the plain reference (``DFG.reference_execute`` folded over the
+invocations, row by row), word for word, in the Pallas interpreter on the
+CPU.
 
 Every Table-I kernel at small dims and the three KWS DS-CNN layers at
 published widths (their invocation lists cut short), at batch 1 on the
@@ -23,6 +24,8 @@ from repro.core.dfg import DFG, Node, Op, Operand, wrap
 from repro.core.kernels_lib import table1_kernels
 from repro.core.verify import generate_test_data_batch
 from repro.frontend import layers
+
+from dfg_reference import reference_rows
 
 TABLE1 = ["GEMM", "GEMM-U", "GEMM-U-C", "CONV", "CONV-U-C-1", "CONV-U-C-2"]
 KWS = ["build_conv2d", "build_dwconv_layer", "build_pwconv"]
@@ -55,7 +58,7 @@ def _extreme_invocations(invocations, seed):
 
 def _bodies(dfg, n_iters, init_banks, invocations, bits):
     """The initial image and the final images of the scan, the kernel
-    (interpreted) and the numpy batch interpreter, each [batch, words]:
+    (interpreted) and the plain reference, each [batch, words]:
     the banks in name order, without the scan's dump cell for dropped
     stores, which no caller reads."""
     names = sorted(init_banks)
@@ -75,8 +78,7 @@ def _bodies(dfg, n_iters, init_banks, invocations, bits):
         refexec._scan_body, p))(*args)).reshape(B, -1)
     vmem = np.asarray(jax.jit(functools.partial(
         refexec._vmem_body, p, interpret=True))(*args)).reshape(B, -1)
-    ref = dfg.reference_execute_batch(n_iters, init_banks, invocations,
-                                      bits=bits)
+    ref = reference_rows(dfg, init_banks, invocations, n_iters, bits)
     ref = np.concatenate([ref[k] for k in names], axis=1)
     return mem0[:, :-1], scan[:, :-1], vmem[:, :-1], ref
 
