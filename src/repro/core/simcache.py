@@ -3,7 +3,7 @@
 Every simulator launch (``simulate``, ``simulate_batch``,
 ``simulate_multi``, all through ``simulator._launch``) takes one XLA
 executable per *shape signature* — ``(II, P, RF, bits, n_iters, n_cycles,
-batch)`` — not per call.  Verifying the six Table-I kernels plus
+batch, pass_words)`` — not per call.  Verifying the six Table-I kernels plus
 the four DSL kernels across N seeds therefore triggers a handful of traces
 instead of one per ``verify`` call, and repeated verification sweeps (CI,
 architecture exploration) reuse the executables for the lifetime of the
@@ -43,6 +43,11 @@ class SimSignature:
     shape bucket; its state-vector layout depends on the live-in register
     count, so ``LI`` joins the key there (the single-config body infers LI
     from the traced live-in stack and keeps the historical key).
+
+    ``pass_words`` is how many image words one LOAD or STORE pass of the
+    single-config VMEM body covers, enough for any of the configuration's
+    banks (``simulator._pass_words``).  The scan has no windows:
+    ``simulator._launch`` keys every scan launch with 0.
     """
     II: int
     P: int
@@ -53,6 +58,7 @@ class SimSignature:
     batch: int
     LI: int = 0
     multi: bool = False
+    pass_words: int = 0
 
 
 def bucket_batch(batch: int) -> int:

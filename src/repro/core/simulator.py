@@ -68,6 +68,7 @@ before entering the traced body: the pre-tiled per-cycle streams shrink
 """
 from __future__ import annotations
 
+import dataclasses
 from collections import OrderedDict
 from typing import Dict, List, Sequence, Tuple
 
@@ -467,7 +468,9 @@ def _sim_body(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
 #     source rows instead and builds the cycle's planes in the kernel
 #     (``_vmem_planes``);
 #   * LOADs and STOREs touch only the slot's load / store lanes (SMEM
-#     tables), by iota compares over the image row; a gated-off store
+#     tables), by iota compares over a window of the image row that holds
+#     the lane's bank (``_pass_words`` lanes from the bank's offset
+#     aligned down to 128, kept inside the image); a gated-off store
 #     writes nothing;
 #   * step t of the cycle loop runs cycle t's mux and ALU beside cycle
 #     t-1's memory work, which depends on nothing of cycle t: a LOAD's word
@@ -478,6 +481,15 @@ _VMEM_BUDGET = 48 << 20
 
 def _lanes(n: int) -> int:
     return -(-n // 128) * 128
+
+
+def _pass_words(cfg: SimConfig) -> int:
+    """Lanes one LOAD or STORE pass of the VMEM body covers for ``cfg``:
+    the fewest whole 128-lane blocks that hold every bank the planes
+    address (``mem_off``, ``mem_words``) from its offset aligned down to
+    128 lanes, at most the image's lanes."""
+    return min(_lanes(int(np.max(cfg.mem_off % 128 + cfg.mem_words))),
+               _lanes(cfg.total_words))
 
 
 def _vmem_layout(P: int, RF: int, LI: int) -> Tuple[int, int, int, int]:
@@ -595,17 +607,20 @@ def _mux(x: jnp.ndarray, oh: jnp.ndarray, bits: int) -> jnp.ndarray:
 
 def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
               li_stack: jnp.ndarray, *, II: int, P: int, RF: int,
-              bits: int, n_iters: int, n_cycles: int,
+              bits: int, n_iters: int, n_cycles: int, pass_words: int,
               interpret: bool = False) -> jnp.ndarray:
     """``_sim_body`` of single-configuration planes as one Pallas kernel
     (see the section comment): the same cycle, word for word, with the
-    loops inside the kernel.  ``interpret=True`` runs it in the Pallas
-    interpreter (the CPU tests)."""
+    loops inside the kernel.  Each LOAD and STORE passes over a window of
+    ``pass_words`` lanes (``_pass_words``) that holds the PE's bank; a
+    window as wide as the image starts at lane 0.  ``interpret=True`` runs
+    it in the Pallas interpreter (the CPU tests)."""
     B, W = mem0.shape
     n_inv, _, LI = li_stack.shape
     XW, PL, CW, OW = _vmem_layout(P, RF, LI)
     NW, KR = XW + OW, XW + PL
     B8, Wp, G = -(-B // 8) * 8, _lanes(W), -(-n_inv // 8)
+    PW = pass_words
     L = c["load_lanes"].shape[1]
     S = c["store_lanes"].shape[1]
     i32 = jnp.int32
@@ -651,7 +666,7 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
                mem_ref, vc_ref):
         mem_ref[...] = mem_in[...]
         lane = jax.lax.broadcasted_iota(i32, (B8, PL), 1)
-        word = jax.lax.broadcasted_iota(i32, (B8, Wp), 1)
+        word = jax.lax.broadcasted_iota(i32, (B8, PW), 1)
         sub = jax.lax.broadcasted_iota(i32, (8, CW), 0)
 
         def lane_of(x, q):        # [B8, PL] -> lane q as [B8, 1]
@@ -661,6 +676,11 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
         def address(a, s, q):     # clipped bank-relative word of lane q
             return moff_ref[s, q] + jnp.clip(lane_of(a, q), 0,
                                              mw_ref[s, q] - 1)
+
+        def bank_pass(s, q):      # lane q's bank as PW lanes of the image
+            start = pl.multiple_of(
+                jnp.clip(moff_ref[s, q] // 128 * 128, 0, Wp - PW), 128)
+            return pl.ds(start, PW), start
 
         def invocation(i, carry):
             li_row = jnp.sum(jnp.where(sub == i % 8, li_ref[i // 8], 0),
@@ -683,9 +703,10 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
 
                     @pl.when((p >= 0) & (t >= vs) & (t < vs + window))
                     def _store():
-                        mem_ref[...] = jnp.where(
-                            word == address(a, s, q), lane_of(b, q),
-                            mem_ref[...])
+                        w, start = bank_pass(s, q)
+                        mem_ref[:, w] = jnp.where(
+                            word == address(a, s, q) - start, lane_of(b, q),
+                            mem_ref[:, w])
 
             def cycle(t, st):
                 # cycle t's mux and ALU beside cycle t-1's memory work:
@@ -705,13 +726,13 @@ def _vmem_sim(c: Dict[str, jnp.ndarray], mem0: jnp.ndarray,
                 res = _alu(o, a, b, p3, bits)
 
                 s_prev = (t + II - 1) % II
-                image = mem_ref[...]
                 for k in range(L):
                     p = jnp.where(t >= 1, lds_ref[s_prev, k], -1)
                     q = jnp.maximum(p, 0)
-                    val = jnp.sum(jnp.where(word == address(a_prev, s_prev,
-                                                            q),
-                                            image, 0), axis=1, keepdims=True)
+                    w, start = bank_pass(s_prev, q)
+                    val = jnp.sum(jnp.where(
+                        word == address(a_prev, s_prev, q) - start,
+                        mem_ref[:, w], 0), axis=1, keepdims=True)
                     ldp = jnp.where(lane == p, val, ldp)
 
                 is_load = o == OPC_LOAD
@@ -774,7 +795,8 @@ def _build_batched(sig: simcache.SimSignature):
         B, W = mem0.shape
         n_inv, _, LI = li_stack.shape
         if _body(False, B, W, sig.P, sig.RF, LI, sig.II, n_inv) == "vmem":
-            return _vmem_sim(c, mem0, li_stack, **static)
+            return _vmem_sim(c, mem0, li_stack, pass_words=sig.pass_words,
+                             **static)
         return _sim_body(c, mem0, li_stack, **static)
 
     def morpher_sim_multi(c, mem0, li_stack):
@@ -795,19 +817,26 @@ def _launch(sig: simcache.SimSignature, planes: Dict, mem: np.ndarray,
     which body ran (``_body``: the VMEM kernel or the scan), the fabric's
     PEs (``pes``), the VMEM the kernel holds for these shapes
     (``vmem_bytes``, ``_vmem_bytes`` as ``_vmem_planes`` would hold the
-    planes), whether the scan took the pre-tiled streams (never on the
-    kernel), and whether this launch built the executable."""
+    planes), the image's lanes (``image_lanes``) and the lanes one LOAD or
+    STORE pass covers (``pass_words``: the bank window on the kernel, the
+    whole image on the scan), whether the scan took the pre-tiled streams
+    (never on the kernel), and whether this launch built the executable."""
     n_inv = int(li_stack.shape[0])
     steps = sig.n_cycles * n_inv
-    shapes = (sig.batch, mem.shape[-1], sig.P, sig.RF, li_stack.shape[-1],
-              sig.II, n_inv)
+    W = mem.shape[-1]
+    shapes = (sig.batch, W, sig.P, sig.RF, li_stack.shape[-1], sig.II,
+              n_inv)
     body = _body(sig.multi, *shapes)
+    if body == "scan":          # the scan has no windows to key on
+        sig = dataclasses.replace(sig, pass_words=0)
     with obs.span("morpher.sim.launch", multi=sig.multi, invocations=n_inv,
                   steps=steps, rows=sig.batch, real_rows=real_rows,
                   row_steps=steps * sig.batch,
                   real_row_steps=real_row_steps, body=body, pes=sig.P,
                   vmem_bytes=_vmem_bytes(
                       *shapes, _vmem_planes(*shapes) != "built"),
+                  image_lanes=_lanes(W),
+                  pass_words=sig.pass_words or _lanes(W),
                   pretiled=(body == "scan"
                             and _pretiled(planes, sig.II, sig.n_cycles)),
                   built=False) as attrs:
@@ -835,6 +864,14 @@ def _mem_to_banks(cfg: SimConfig, mem: np.ndarray,
             for bid, off in cfg.bank_offsets.items()}
 
 
+def _signature(cfg: SimConfig, n_iters: int, n_cycles: int,
+               batch: int) -> simcache.SimSignature:
+    """The executable signature of a single-configuration launch."""
+    return simcache.SimSignature(
+        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
+        n_cycles=n_cycles, batch=batch, pass_words=_pass_words(cfg))
+
+
 def simulate(cfg: SimConfig, banks: Dict[str, np.ndarray],
              invocations, n_iters: int) -> Dict[str, np.ndarray]:
     """Run the mapped kernel for every invocation and return final banks.
@@ -857,9 +894,7 @@ def simulate(cfg: SimConfig, banks: Dict[str, np.ndarray],
             return _mem_to_banks(cfg, mem, banks)
         li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
         n_cycles = cfg.n_cycles(n_iters)
-        sig = simcache.SimSignature(
-            II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
-            n_cycles=n_cycles, batch=1)
+        sig = _signature(cfg, n_iters, n_cycles, batch=1)
         planes = _as_jnp(cfg)
     out = _launch(sig, planes, mem[None, :], li_stack, real_rows=1,
                   real_row_steps=n_cycles * len(invocations))
@@ -890,10 +925,8 @@ def simulate_batch(cfg: SimConfig, banks_batch: List[Dict[str, np.ndarray]],
                     for i in range(B)]
         li_stack = np.stack([cfg.livein_array(inv) for inv in invocations])
         real_cycles = cfg.n_cycles(n_iters)
-        sig = simcache.SimSignature(
-            II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=n_iters,
-            n_cycles=simcache.bucket_cycles(real_cycles),
-            batch=simcache.bucket_batch(B))
+        sig = _signature(cfg, n_iters, simcache.bucket_cycles(real_cycles),
+                         batch=simcache.bucket_batch(B))
         if sig.batch > B:  # pad to the bucket; padded rows masked out below
             mem = np.concatenate(
                 [mem, np.repeat(mem[-1:], sig.batch - B, axis=0)])

@@ -197,7 +197,8 @@ def test_layer_vmem_body_matches_scan(monkeypatch, kind, planes):
     scan = np.asarray(jax.jit(functools.partial(
         simulator._sim_body, **static))(*args))
     vmem = np.asarray(jax.jit(functools.partial(
-        simulator._vmem_sim, interpret=True, **static))(*args))
+        simulator._vmem_sim, interpret=True,
+        pass_words=simulator._pass_words(cfg), **static))(*args))
     np.testing.assert_array_equal(vmem, scan)
     for row, s in enumerate((5, 6)):
         want = simulator._banks_to_mem(cfg, _images(kind, s)[1])

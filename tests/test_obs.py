@@ -103,6 +103,8 @@ def test_launch_counters_match_shapes(ck, monkeypatch):
                      "real_row_steps": real * n_inv * 3, "body": body,
                      "pes": cfg.P,
                      "vmem_bytes": simulator._vmem_bytes(*shapes),
+                     "image_lanes": simulator._lanes(cfg.total_words),
+                     "pass_words": simulator._lanes(cfg.total_words),
                      "pretiled": True, "built": True}
     ck.run_batch(banks)
     assert _last("morpher.sim.launch")["attrs"]["built"] is False
@@ -139,6 +141,8 @@ def test_launch_body_follows_dispatch(ck, monkeypatch):
     vmem = ck.run_batch(banks)
     attrs = _last("morpher.sim.launch")["attrs"]
     assert (attrs["body"], attrs["pretiled"]) == ("vmem", False)
+    # each LOAD and STORE passes over one 4,096-word bank, not the image
+    assert (attrs["pass_words"], attrs["image_lanes"]) == (4096, 8320)
     for a, b in zip(scan, vmem):
         for bank in a:
             np.testing.assert_array_equal(a[bank], b[bank])
@@ -248,9 +252,8 @@ def test_device_programs_have_stable_names(ck):
     from repro.core.dfg import Op
     from repro.core.refexec import _lowered
     cfg, n_inv = ck.cfg, len(ck.invocations)
-    sig = simcache.SimSignature(
-        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=ck.mapped_iters,
-        n_cycles=cfg.n_cycles(ck.mapped_iters), batch=2)
+    sig = simulator._signature(cfg, ck.mapped_iters,
+                               cfg.n_cycles(ck.mapped_iters), batch=2)
     mem = jnp.zeros((2, cfg.total_words), jnp.int16)
     li = jnp.zeros((n_inv, cfg.P, max(1, cfg.LI)), jnp.int32)
     planes = simulator._as_jnp(cfg)

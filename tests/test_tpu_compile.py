@@ -24,7 +24,7 @@ from repro.core.dfg import Op
 from repro.core.kernels_lib import table1_kernels
 from repro.core.refexec import _lowered
 from repro.core.simulator import (_body, _build_batched, _host_planes,
-                                  _stack_planes)
+                                  _signature, _stack_planes)
 from repro.core.toolchain import Toolchain
 from repro.kernels.gemm_os.kernel import gemm_os_pallas
 from repro.models.zoo import build_model
@@ -73,12 +73,11 @@ def _shapes(tree, sharding):
 
 def _compile_batch8(ck, sharding):
     """The batch-8 executable of a compiled kernel, as ``simulate_batch``
-    launches it, compiled for the described chip; its text."""
+    launches it (LOAD and STORE passes over one bank's window), compiled
+    for the described chip; its text."""
     cfg, n_inv = ck.cfg, len(ck.invocations)
-    sig = simcache.SimSignature(
-        II=cfg.II, P=cfg.P, RF=cfg.RF, bits=cfg.bits, n_iters=ck.mapped_iters,
-        n_cycles=simcache.bucket_cycles(cfg.n_cycles(ck.mapped_iters)),
-        batch=8)
+    sig = _signature(cfg, ck.mapped_iters, simcache.bucket_cycles(
+        cfg.n_cycles(ck.mapped_iters)), batch=8)
     mem = np.zeros((8, cfg.total_words), np.int16)
     li = np.zeros((n_inv, cfg.P, max(1, cfg.LI)), np.int32)
     assert _body(False, 8, cfg.total_words, cfg.P, cfg.RF, max(1, cfg.LI),
@@ -89,6 +88,8 @@ def _compile_batch8(ck, sharding):
 
 
 def test_simulator_paper_gemm_batch8(one_chip, chip_backend, paper_gemm):
+    # the launch's ``pass_words``: one 4,096-word bank of the 8,320 lanes
+    assert _signature(paper_gemm.cfg, 1, 1, 8).pass_words == 4096
     text = _compile_batch8(paper_gemm, one_chip)
     assert "tpu_custom_call" in text                    # the VMEM kernel
     assert "input_output_alias" in text                 # image donated
@@ -121,6 +122,8 @@ def test_simulator_kws_layers_64_pes(one_chip, chip_backend, build, ii_start,
     assert cfg.P == 64
     assert _vmem_planes(8, cfg.total_words, cfg.P, cfg.RF, max(1, cfg.LI),
                         cfg.II, len(ck.invocations)) == planes
+    # the launch's ``pass_words``: one 4,096-word bank of the 32,896 lanes
+    assert _signature(cfg, 1, 1, 8).pass_words == 4096
     text = _compile_batch8(ck, one_chip)
     assert "tpu_custom_call" in text and "input_output_alias" in text
 
